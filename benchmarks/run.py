@@ -884,6 +884,10 @@ def main() -> None:
                     help="run a single benchmark (CI smoke)")
     args = ap.parse_args()
     import os
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     os.makedirs("results", exist_ok=True)
     print("name,us_per_call,derived")
     if args.only is not None:

@@ -17,7 +17,7 @@ durable :class:`CompiledBankingPlan` that owns everything execution needs:
 * ``pack`` / ``unpack`` between logical row-major arrays and bank-major
   storage (reference Eq. 1-2 arithmetic, vectorized);
 * ``gather(table, rows)`` binding the Pallas banked-gather kernel with the
-  compiled resolution arithmetic in its index map;
+  compiled resolution arithmetic addressing its row DMAs;
 * ``scatter(table, rows, values)`` -- the write path through the same
   circuit (full rows, or single columns for per-slot record writes);
 * ``to_partition_spec(mesh_axes)`` mapping the banked dimensions onto mesh
@@ -239,7 +239,8 @@ class CompiledBankingPlan:
         """(bank, offset) of a flat logical address (scalar or array).
 
         This is the Eq. 1-2 resolution circuit, lowered through the Sec-3.4
-        transforms -- the same callables the gather kernel's index map runs.
+        transforms -- the same callables that address the gather kernel's
+        row DMAs.
         """
         xs = self._split(addr)
         return self.ba(*xs), self.bo(*xs)
@@ -369,8 +370,11 @@ class CompiledBankingPlan:
         per-row-set calls.
 
         ``jax`` backend: binds the Pallas banked-gather kernel -- the
-        compiled BA/BO arithmetic runs in the scalar-prefetch index map,
-        exactly where an FPGA would place the resolution circuit.
+        compiled BA/BO arithmetic addresses each row DMA from the
+        prefetched index, exactly where an FPGA would place the
+        resolution circuit.  ``interpret=None`` runs the kernel body
+        in the Pallas interpreter off the TPU
+        (:func:`repro.kernels.ops.default_interpret`).
         ``numpy`` backend: direct advanced indexing through the same
         compiled (numpy-lowered) resolution callables.
         """
@@ -380,10 +384,10 @@ class CompiledBankingPlan:
             ba, bo = self.resolve(np.asarray(rows, dtype=np.int64))
             return np.asarray(table)[ba, bo]
         from ..kernels.banked_gather import banked_gather
+        from ..kernels.ops import default_interpret
 
         if interpret is None:
-            import jax
-            interpret = jax.default_backend() != "tpu"
+            interpret = default_interpret()
 
         def ba_fn(addr):
             return self.ba(*self._split(addr))
@@ -416,8 +420,8 @@ class CompiledBankingPlan:
         updated table (duplicates resolve last-write-wins).
 
         ``jax`` backend: binds the Pallas banked-scatter kernel -- the
-        compiled BA/BO arithmetic runs in the out-spec index map, in
-        front of the memory like the gather's.  ``numpy`` backend:
+        compiled BA/BO arithmetic addresses each row DMA, in front of
+        the memory like the gather's.  ``numpy`` backend:
         advanced-indexing assignment through the same compiled
         resolution callables.
         """
@@ -431,10 +435,10 @@ class CompiledBankingPlan:
             return out
         from ..kernels.banked_gather import (banked_scatter,
                                              banked_scatter_elems)
+        from ..kernels.ops import default_interpret
 
         if interpret is None:
-            import jax
-            interpret = jax.default_backend() != "tpu"
+            interpret = default_interpret()
 
         def ba_fn(addr):
             return self.ba(*self._split(addr))

@@ -44,6 +44,7 @@ from __future__ import annotations
 import itertools
 import math
 import pickle
+import sys
 import threading
 import time
 import zlib
@@ -656,6 +657,19 @@ def _pool_eval(idxs: List[int]) -> List[EvaluatedCandidate]:
     return list(evaluate(shard_from_indices(_POOL_SPACE, idxs)))
 
 
+def _holds_accelerator() -> bool:
+    """True once this process has brought up a non-CPU JAX backend.  A
+    forked child would inherit the parent's hold on the chip, so the
+    fork pool stays out of such a process."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 def evaluate_parallel(space: CandidateSpace, workers: int, *,
                       scorer: Optional[Callable] = None,
                       chunk: int = 24,
@@ -668,11 +682,11 @@ def evaluate_parallel(space: CandidateSpace, workers: int, *,
     reached, none of its remaining candidates are ever dispatched.
     Total work therefore stays close to the monolithic search's while
     the evaluation wall-clock divides across processes.  Falls back to
-    :func:`solve_space` when ``workers <= 1`` or the platform cannot
-    fork.
+    :func:`solve_space` when ``workers <= 1``, the platform cannot fork,
+    or this process holds an accelerator (one process per chip).
     """
     red = reducer or SolutionReducer(space, scorer=scorer)
-    if workers <= 1 or len(space) == 0:
+    if workers <= 1 or len(space) == 0 or _holds_accelerator():
         solve_space(space, reducer=red)
         return red
     import multiprocessing as mp

@@ -66,17 +66,9 @@ _MAX_SAMPLES = 64
 
 
 def roofline_bandwidth() -> float:
-    """HBM bytes/s from ``launch/roofline.py``'s constants (cached;
-    falls back to the TPU v5e figure if the launch stack won't import)."""
-    cached = roofline_bandwidth.__dict__.get("_bw")
-    if cached is None:
-        try:
-            from ..launch.roofline import HBM_BW as bw
-        except Exception:  # headless core-only installs
-            bw = 819e9
-        cached = float(bw)
-        roofline_bandwidth.__dict__["_bw"] = cached
-    return cached
+    """HBM bytes/s from ``launch/roofline.py``'s peaks table."""
+    from ..launch.roofline import HBM_BW
+    return float(HBM_BW)
 
 
 def scheme_hash(obj) -> str:

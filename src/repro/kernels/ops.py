@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the kernel bodies execute (and are
-tested) on CPU; on TPU the same calls compile through Mosaic.
+``interpret=None`` resolves through :func:`default_interpret`, the one place
+that decides it: on a TPU backend the kernels compile through Mosaic, and
+anywhere else (the CPU test suite) their bodies run in the Pallas
+interpreter.
 """
 
 from __future__ import annotations
@@ -17,7 +19,8 @@ from .moe_dispatch import moe_combine, moe_dispatch
 from .ssd_chunk import ssd_chunk
 
 
-def _default_interpret() -> bool:
+def default_interpret() -> bool:
+    """Interpret kernel bodies unless the default backend is a TPU."""
     return jax.default_backend() != "tpu"
 
 
@@ -30,7 +33,7 @@ def mha(q, k, v, *, causal=True, window=0, kv_len=None,
     q: (B, Sq, H, Dh); k/v: (B, Sk, Hkv, Dh).  GQA is folded: each kv head
     serves H//Hkv query heads through the leading grid axis.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     B, Sq, H, Dh = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     rep = H // Hkv
@@ -46,7 +49,7 @@ def mha(q, k, v, *, causal=True, window=0, kv_len=None,
 def gather_banked(table, indices, compiled, *, interpret=None):
     """Gather logical rows from a bank-major table through a compiled
     banking artifact (``plan.compile()``); its strength-reduced resolution
-    arithmetic runs in the Pallas index map (see kernels/banked_gather.py).
+    arithmetic addresses the kernel's row DMAs (see kernels/banked_gather.py).
 
     ``indices`` may be a flat ``(T,)`` vector or a stacked ``(T, R)``
     matrix of row-sets (one decode tick's reads for every active
@@ -55,7 +58,6 @@ def gather_banked(table, indices, compiled, *, interpret=None):
 
     Accepts a ``CompiledBankingPlan`` or a ``BankingPlan``; passing a raw
     ``BankingSolution`` still works but is deprecated."""
-    interpret = _default_interpret() if interpret is None else interpret
     return as_compiled(compiled).gather(table, indices, interpret=interpret)
 
 
@@ -68,9 +70,8 @@ def scatter_banked(table, indices, values, compiled, *, col=None,
     ``col=None``, ``values`` is ``(T, D)`` replacement rows; with
     ``col`` a ``(T,)`` vector of column indices, ``values`` is ``(T,)``
     scalars -- the serving runtime's batched per-slot token-record
-    write.  Returns the updated table; the resolution arithmetic runs in
-    the Pallas out-spec index map (see kernels/banked_gather.py)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    write.  Returns the updated table; the resolution arithmetic
+    addresses the kernel's row DMAs (see kernels/banked_gather.py)."""
     return as_compiled(compiled).scatter(table, indices, values, col=col,
                                          interpret=interpret)
 
@@ -83,15 +84,15 @@ def pack_banked(flat, compiled):
 
 
 def dispatch(x, slot_token, *, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     x_padded = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
     return moe_dispatch(x_padded, slot_token, interpret=interpret)
 
 
 def ssd(x, dt, bm, cm, cum, s_prev, *, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return ssd_chunk(x, dt, bm, cm, cum, s_prev, interpret=interpret)
 
 
-__all__ = ["dispatch", "gather_banked", "mha", "moe_combine", "pack_banked",
-           "scatter_banked", "ssd"]
+__all__ = ["default_interpret", "dispatch", "gather_banked", "mha",
+           "moe_combine", "pack_banked", "scatter_banked", "ssd"]

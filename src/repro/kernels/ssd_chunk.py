@@ -20,8 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
 
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, cum_ref, s_ref,
@@ -85,7 +85,7 @@ def ssd_chunk(x, dt, bm, cm, cum, s_prev, *, interpret=False):
             jax.ShapeDtypeStruct((B, H, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, dt, bm, cm, cum, s_prev)

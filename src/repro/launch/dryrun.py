@@ -283,9 +283,6 @@ def lower_cell(arch: str, shape_name: str, mesh: Mesh, *,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # older JAX returns a one-element list of per-computation dicts
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     try:
         hlo = compiled.as_text()
     except Exception:

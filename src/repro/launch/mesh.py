@@ -8,6 +8,14 @@ XLA_FLAGS before importing anything else).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the models place arrays with
+    sharding hints and let XLA propagate the rest, which explicit axes
+    (``jax.make_mesh``'s default) would refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,10 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     once per step (gradient all-reduce)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has -- smoke tests and examples."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"))

@@ -121,6 +121,10 @@ def main():
 
     import numpy as np
 
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from ..configs import get_arch
     from ..core.fabric import SolveFabric
     from ..core.service import PlanService

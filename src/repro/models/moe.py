@@ -139,7 +139,6 @@ def moe_ffn_a2a(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
     Requires a live mesh in the hint policy; falls back to the sorted
     implementation otherwise (single-device smoke tests).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.hints import policy_value
@@ -209,12 +208,12 @@ def moe_ffn_a2a(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
 
     w_up_spec = P("model", None, "data" if fsdp_weights else None)
     w_dn_spec = P("model", "data" if fsdp_weights else None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   w_up_spec, w_up_spec, w_dn_spec),
         out_specs=(P(dp, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(h, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"])
     return out, aux

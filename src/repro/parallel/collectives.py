@@ -55,10 +55,8 @@ def compressed_grad_mean(grads: Any, residuals: Any, mesh: Mesh,
     grads/residuals: pytrees replicated over `axis` (i.e. per-shard partial
     gradients).  Returns (mean_grads, new_residuals).
     """
-    from jax.experimental.shard_map import shard_map
-
     def one(g, r):
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(compressed_psum_leaf, axis=axis),
             mesh=mesh,
             in_specs=(P(*([None] * g.ndim)), P(*([None] * g.ndim))),

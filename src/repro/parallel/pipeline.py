@@ -20,7 +20,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -67,10 +66,10 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         outs = jax.lax.psum(outs, axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),     # params stage-sharded, x replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -22,8 +22,8 @@ ticks once the background solve lands.
 Each decode tick reads its per-slot token records through **one batched
 banked gather** (a single ``pallas_call`` over a stacked ``(slots, W)``
 index matrix) instead of one kernel launch per row-set -- the compiled
-resolution arithmetic runs in the kernel's scalar-prefetch index map
-either way, so the scheduler and the gather agree on the layout by
+resolution arithmetic addresses the kernel's row DMAs from the
+prefetched indices either way, so the scheduler and the gather agree on the layout by
 construction.  Writes go the same way: token records queue per tick and
 flush through **one batched banked scatter** (``artifact.scatter`` with
 per-slot column indices), so the resolution circuit -- not host-side
@@ -322,6 +322,10 @@ class Server:
         self.kv_records = None    # bank-major (banks, vol, max_batch) int32
         self._pending_records: List[tuple] = []   # (pos, slot, tok) queue
         self._gather_window = min(4, max_len)
+        # every gathered decode input is checked against the token the
+        # host recorded: checks per serving layout, and the mismatches
+        self.record_checks: Dict[str, int] = {}
+        self.record_mismatches = 0
         if art is not None:
             self._adopt_kv_artifact(art, records=None)
         self.swaps = 0
@@ -390,10 +394,15 @@ class Server:
             rows[i] = np.clip(np.arange(pos - W, pos), 0, self.max_len - 1)
         got = self._kv_art.gather(self.kv_records, jnp.asarray(rows))
         got = np.asarray(got)                      # (slots, W, max_batch)
+        layout = self._kv_art.describe()
         out = {}
         for i, s in enumerate(slots):
             if int(self.positions[s]) <= self.max_len:
                 out[s] = int(got[i, -1, s])
+                self.record_checks[layout] = \
+                    self.record_checks.get(layout, 0) + 1
+                if out[s] != self.active[s]._next:
+                    self.record_mismatches += 1
             else:  # records past max_len aren't stored; fall back
                 out[s] = getattr(self.active[s], "_next", 1)
         return out

@@ -168,6 +168,36 @@ def test_evaluate_parallel_matches_single_shard():
     assert [_key(s) for s in red.finalize()] == want
 
 
+def test_evaluate_parallel_stays_in_process_on_an_accelerator(monkeypatch):
+    """A process that holds a chip never forks the pool: the solve runs
+    in-process and still returns the monolithic answer."""
+    import concurrent.futures
+
+    from repro.core import candidates as cand_mod
+
+    def no_fork(*a, **k):
+        raise AssertionError("forked a pool while holding a chip")
+
+    monkeypatch.setattr(cand_mod, "_holds_accelerator", lambda: True)
+    monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor", no_fork)
+    mem, groups, iters = _problem("sobel")
+    want = [_key(s) for s in solve(mem, groups, iters)]
+    space = CandidateSpace(mem, groups, iters, SolverOptions())
+    red = evaluate_parallel(space, 2)
+    assert [_key(s) for s in red.finalize()] == want
+
+
+def test_cpu_backend_does_not_count_as_holding_a_chip():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.candidates import _holds_accelerator
+
+    jnp.zeros(1).block_until_ready()          # bring a backend up
+    assert jax.default_backend() == "cpu"
+    assert not _holds_accelerator()
+
+
 # ---------------------------------------------------------------------------
 # Reducer semantics
 # ---------------------------------------------------------------------------

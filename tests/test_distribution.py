@@ -29,7 +29,8 @@ def test_param_specs_roles():
     cfg = dataclasses.replace(get_arch("deepseek_67b"), n_layers=2)
     model = get_model(cfg)
     shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     # fake a 16-wide model axis by asking bankable directly: use specs from
     # the production shape via a mesh-shaped namespace
@@ -63,7 +64,8 @@ def test_pipeline_matches_sequential():
         from repro.parallel.pipeline import pipeline_apply
 
         S, L_per, M, mb, D = 4, 2, 8, 4, 16
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = jax.make_mesh((S,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         Ws = jnp.asarray(rng.normal(size=(S, L_per, D, D)) * 0.2, jnp.float32)
 
@@ -95,7 +97,8 @@ def test_mini_dryrun_multipod():
         import repro.launch.dryrun as dr
         import repro.configs as C
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         base = C.get_arch("whisper_base")
         cfg = dataclasses.replace(base.reduced(), n_heads=4, n_kv_heads=2)
         C_get = C.get_arch

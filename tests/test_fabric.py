@@ -77,6 +77,23 @@ def cluster2():
 # ---------------------------------------------------------------------------
 
 
+def test_solve_worker_never_imports_jax():
+    """Solve workers start beside a server that may hold the chip: the
+    worker module must not import JAX, so it can never claim a device."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    code = ("import sys, repro.launch.solve_worker; "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env=dict(os.environ, PYTHONPATH=src))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "False"
+
+
 def test_wire_codecs_round_trip():
     """Space and event streams survive the wire byte-for-byte: a decoded
     space evaluates a leased work unit to identical results."""

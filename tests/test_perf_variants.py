@@ -79,7 +79,8 @@ def test_moe_a2a_matches_oracle_on_mesh():
 
         cfg = dataclasses.replace(get_arch("olmoe_1b_7b").reduced(),
                                   n_experts=4, top_k=2, capacity_factor=8.0)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         params = moe_mod.init_moe_params(cfg, jax.random.PRNGKey(0))
         lp = jax.tree.map(lambda x: x[0], params["layers"])
         h = jnp.asarray(np.random.default_rng(0).normal(

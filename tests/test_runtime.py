@@ -69,7 +69,8 @@ def test_ckpt_elastic_reshard(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     state = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     mgr.save(1, state)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     shardings = {"w": NamedSharding(mesh, P("model", None))}
     restored, _ = mgr.restore(state, shardings=shardings)
     np.testing.assert_array_equal(np.asarray(restored["w"]),
