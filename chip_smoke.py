@@ -68,6 +68,7 @@ def main() -> None:
     from repro.configs import qwen2_7b
     from repro.core.planner import BankingPlanner
     from repro.core.service import PlanService
+    from repro.core.tracing import serve_tracer
     from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.steps import make_serve_step
     from repro.models import get_model
@@ -111,23 +112,8 @@ def main() -> None:
     check(step_bytes < limit, "the decode step does not fit the chip")
 
     # -- serve ---------------------------------------------------------------
-    compiles = {"backend": 0, "cache_hits": 0, "cache_misses": 0}
-    counting = threading.Event()
-
-    def on_duration(event, duration, **kw):
-        if counting.is_set() and \
-                event == "/jax/core/compile/backend_compile_duration":
-            compiles["backend"] += 1
-
-    def on_event(event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            compiles["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            compiles["cache_misses"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-
+    # the server's own counters: compiles inside its serve.* spans
+    counters = serve_tracer().metrics
     release = threading.Event()
 
     class HeldPlanner(BankingPlanner):
@@ -172,7 +158,8 @@ def main() -> None:
           f"from {fallback.describe()} (server built in {t_init:.3f} s)",
           flush=True)
 
-    counting.set()
+    compiles0 = counters.counter("compiles")
+    reads0 = counters.counter("compile_cache_reads")
     t0 = time.perf_counter()
     server.tick()                 # admission, then tick 1 on the fallback
     check(server.ticks == 1 and server.pager.artifact is fallback,
@@ -181,7 +168,8 @@ def main() -> None:
     check(ticket.wait(SOLVE_TIMEOUT_S), "the KV plan's solve never landed")
     server.run(max_ticks=8 * NEW_TOKENS)
     wall = time.perf_counter() - t0
-    counting.clear()
+    compiles = counters.counter("compiles") - compiles0
+    reads = counters.counter("compile_cache_reads") - reads0
 
     solved = server.pager.artifact
     tokens = sum(len(r.out) for r in requests)
@@ -193,10 +181,9 @@ def main() -> None:
           f"now serving {solved.describe()}", flush=True)
     print(f"record checks per layout: {server.record_checks}; "
           f"mismatches={server.record_mismatches}", flush=True)
-    print(f"compilations while serving: {compiles['backend']} "
-          f"({server.ticks} ticks); persistent cache over the whole run: "
-          f"hits={compiles['cache_hits']} misses={compiles['cache_misses']}",
-          flush=True)
+    print(f"compilations while serving: {compiles:g} "
+          f"({server.ticks} ticks); persistent cache reads while serving: "
+          f"{reads:g}", flush=True)
     peak = dev.memory_stats().get("peak_bytes_in_use")
     print(f"peak_bytes_in_use: {peak}", flush=True)
 

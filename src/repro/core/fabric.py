@@ -62,6 +62,7 @@ from .candidates import (
     shard_from_indices,
     space_to_wire,
 )
+from .tracing import span_or_null
 
 _LEN = struct.Struct("!I")
 # Hard ceiling audited BEFORE any allocation or unpickle: a corrupt or
@@ -672,14 +673,12 @@ class SolveFabric:
         # encoding the space (pickle + zlib) can take a while for big
         # problems: do it before touching the fabric lock so concurrent
         # solves' result intake and dispatch never stall behind it
-        t_ser = time.perf_counter()
-        solve = _FabricSolve(self._next_solve(), space, red,
-                             verifier=verifier, lease_cap=lease_cap,
-                             trace=trace)
-        if trace is not None:
-            trace[0].record(trace[1], "serialize", t_ser,
-                            time.perf_counter(),
-                            bytes=len(solve.payload), candidates=n)
+        tr, tid = trace if trace is not None else (None, None)
+        with span_or_null(tr, tid, "serialize", candidates=n) as span:
+            solve = _FabricSolve(self._next_solve(), space, red,
+                                 verifier=verifier, lease_cap=lease_cap,
+                                 trace=trace)
+            span.attrs["bytes"] = len(solve.payload)
         for lo in range(0, n, step):
             solve.pending.append(
                 _Unit(indices=tuple(range(lo, min(lo + step, n)))))
@@ -704,15 +703,13 @@ class SolveFabric:
                     if not idxs:
                         continue
                     local = 0
-                    t_loc = time.perf_counter()
-                    for ev in evaluate(shard_from_indices(space, idxs),
-                                       gate=red):
-                        red.add(ev)
-                        local += 1
-                    if trace is not None:
-                        trace[0].record(trace[1], "local-eval", t_loc,
-                                        time.perf_counter(),
-                                        units=len(idxs), evaluated=local)
+                    with span_or_null(tr, tid, "local-eval",
+                                      units=len(idxs)) as span:
+                        for ev in evaluate(shard_from_indices(space, idxs),
+                                           gate=red):
+                            red.add(ev)
+                            local += 1
+                        span.attrs["evaluated"] = local
                     with self._lock:
                         solve.report.local_evaluated += local
                         self.stats.local_evaluated += local

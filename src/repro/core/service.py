@@ -100,7 +100,7 @@ from .jointplan import (
 from .polytope import MemorySpec
 from .solver import BankingSolution, SolverOptions
 from .store import PlanStore, as_store
-from .tracing import NULL_SPAN, new_trace_id
+from .tracing import new_trace_id, span_or_null
 
 
 @dataclass
@@ -540,15 +540,12 @@ class JointTicket:
         with self._lock:
             if stamp == self._stamp and self._selection is not None:
                 return self._selection
-        tr = self._service.tracer
-        cs_stats = {} if tr is not None else None
-        t_sel = time.perf_counter()
-        frontiers = {n: self._frontier_for(n) for n in self.members}
-        sel = co_select(frontiers, self.budget, stats_out=cs_stats)
-        if tr is not None and self.trace_id is not None:
-            tr.record(self.trace_id, "co-select", t_sel,
-                      time.perf_counter(), progressive=True,
-                      **(cs_stats or {}))
+        cs_stats = {} if self._service.tracer is not None else None
+        with span_or_null(self._service.tracer, self.trace_id, "co-select",
+                          progressive=True) as span:
+            frontiers = {n: self._frontier_for(n) for n in self.members}
+            sel = co_select(frontiers, self.budget, stats_out=cs_stats)
+            span.attrs.update(cs_stats or {})
         with self._lock:
             if sel.key() != self._sel_key:
                 self._version += 1
@@ -657,11 +654,10 @@ class JointTicket:
         certs: Dict[str, Optional[dict]] = {}
         while True:
             cs_stats = {} if tr is not None else None
-            t_sel = time.perf_counter()
-            sel = co_select(frontiers, self.budget, stats_out=cs_stats)
-            if tid is not None:
-                tr.record(tid, "co-select", t_sel, time.perf_counter(),
-                          final=True, **(cs_stats or {}))
+            with span_or_null(tr, tid, "co-select", final=True) as span:
+                sel = co_select(frontiers, self.budget,
+                                stats_out=cs_stats)
+                span.attrs.update(cs_stats or {})
             if self.verify == "off":
                 break
             evicted = False
@@ -1052,16 +1048,13 @@ class PlanService:
         -- ``ticket.deferred`` -- and the fallback artifact still serves
         immediately), and its stats slice records the submit.
         """
-        tr = self.tracer
-        trace_id = new_trace_id() if tr is not None else None
-        t_prep = time.perf_counter()
-        prep = self.planner.prepare(program, memory, opts=opts,
-                                    scorer=scorer, use_cache=use_cache)
-        if tr is not None:
-            # the ticket doesn't exist yet: the trace does, and the
-            # prepare stage is its first span
-            tr.record(trace_id, "prepare", t_prep, time.perf_counter(),
-                      memory=prep.memory)
+        # the ticket doesn't exist yet: the trace does, and the prepare
+        # stage is its first span
+        trace_id = new_trace_id() if self.tracer is not None else None
+        with span_or_null(self.tracer, trace_id, "prepare") as span:
+            prep = self.planner.prepare(program, memory, opts=opts,
+                                        scorer=scorer, use_cache=use_cache)
+            span.attrs["memory"] = prep.memory
         return self.submit_prepared(prep, priority=priority,
                                     shard_budget=shard_budget,
                                     executor=executor, verify=verify,
@@ -1098,16 +1091,13 @@ class PlanService:
             # lint before anything queues: problems no banking can fix
             # (OOB accesses, colliding Syms, oversubscribed ports) must
             # fail the submit, not burn a solve
-            with (tr.span(trace_id, "lint") if tr is not None
-                  else NULL_SPAN):
+            with span_or_null(tr, trace_id, "lint"):
                 self._lint_gate(prep, ten.name)
         key = (prep.signature, prep.scorer_name)
         if prep.request.use_cache:
-            t_look = time.perf_counter()
-            hit = self.planner.lookup(prep)
-            if tr is not None:
-                tr.record(trace_id, "lookup", t_look, time.perf_counter(),
-                          hit=hit is not None)
+            with span_or_null(tr, trace_id, "lookup") as span:
+                hit = self.planner.lookup(prep)
+                span.attrs["hit"] = hit is not None
             if hit is not None:
                 self.stats.bump("sync_hits", tenant=ten.name)
                 ticket = PlanTicket(service=self, prep=prep,
@@ -1245,21 +1235,19 @@ class PlanService:
         ten = self.tenants.resolve(tenant)
         tr = self.tracer
         trace_id = new_trace_id() if tr is not None else None
-        # member prep is the same cheap inline half as submit(): bad
-        # memories and unknown scorers raise here, on the caller
-        t_prep = time.perf_counter()
-        preps = {name: self.planner.prepare(req.program, name,
-                                            opts=req.opts, scorer=req.scorer,
-                                            use_cache=req.use_cache)
-                 for name in names}
-        scorer_name = next(iter(preps.values())).scorer_name
-        signature = joint_signature(
-            {n: p.signature for n, p in preps.items()}, scorer_name,
-            req.budget)
         if tr is not None:
             tr.label(trace_id, f"joint {len(names)} memories")
-            tr.record(trace_id, "joint-prepare", t_prep,
-                      time.perf_counter(), members=len(names))
+        # member prep is the same cheap inline half as submit(): bad
+        # memories and unknown scorers raise here, on the caller
+        with span_or_null(tr, trace_id, "joint-prepare",
+                          members=len(names)):
+            preps = {name: self.planner.prepare(
+                req.program, name, opts=req.opts, scorer=req.scorer,
+                use_cache=req.use_cache) for name in names}
+            scorer_name = next(iter(preps.values())).scorer_name
+            signature = joint_signature(
+                {n: p.signature for n, p in preps.items()}, scorer_name,
+                req.budget)
         self.stats.bump("joint_submits", tenant=ten.name)
         ticket = JointTicket(service=self, request=req, preps=preps,
                              signature=signature, scorer_name=scorer_name,
@@ -1325,12 +1313,10 @@ class PlanService:
         def verify(plan: BankingPlan, prep: PreparedRequest) -> None:
             from ..analysis.certify import CertificationError, certify_plan
             tr = self.tracer
-            t_cert = time.perf_counter()
-            res = certify_plan(plan, prep.iterators,
-                               scorer=prep.scorer_name)
-            if tr is not None and trace_id is not None:
-                tr.record(trace_id, "certify", t_cert,
-                          time.perf_counter(), ok=res.ok)
+            with span_or_null(tr, trace_id, "certify") as span:
+                res = certify_plan(plan, prep.iterators,
+                                   scorer=prep.scorer_name)
+                span.attrs["ok"] = res.ok
             if not res.ok:
                 with self._lock:
                     self.stats.bump("cert_failures", tenant=tenant)
@@ -1422,13 +1408,10 @@ class PlanService:
         training) stays off the submitter's thread, exactly like the
         old monolithic solve."""
         self.planner.stats.misses += 1
-        tr = self.tracer
-        tid = ticket.trace_id if tr is not None else None
-        t_enum = time.perf_counter()
-        space = self.planner.build_space(prep)
-        if tid is not None:
-            tr.record(tid, "enumerate", t_enum, time.perf_counter(),
-                      candidates=len(space))
+        tid = ticket.trace_id if self.tracer is not None else None
+        with span_or_null(self.tracer, tid, "enumerate") as span:
+            space = self.planner.build_space(prep)
+            span.attrs["candidates"] = len(space)
         _, scorer_fn = resolve_scorer(prep.scorer_spec)
         if self.telemetry is not None:
             # a "measured" scorer ranks on THIS service's observation log
@@ -1497,26 +1480,23 @@ class PlanService:
         tr = self.tracer
         tid = ticket.trace_id if tr is not None else None
         try:
-            t_fab = time.perf_counter()
-            report = fabric.solve(space, reducer=reducer,
-                                  verifier=verifier, lease_cap=lease_cap,
-                                  trace=((tr, tid) if tid is not None
-                                         else None))
-            t_red = time.perf_counter()
-            if tid is not None:
-                tr.record(tid, "fabric-solve", t_fab, t_red,
-                          leases=report.leases,
-                          requeues=report.requeues,
-                          workers_lost=report.workers_lost)
-            plan = self.planner.complete_solve(
-                prep, reducer.finalize(),
-                time.perf_counter() - started, scorer_fn,
-                verify=self._make_verifier(ticket.verify, ticket.tenant,
-                                           trace_id=tid))
-            if tid is not None:
-                tr.record(tid, "reduce", t_red, time.perf_counter(),
-                          promotions=reducer.promotions,
-                          dedup_hits=reducer.dedup_hits)
+            with span_or_null(tr, tid, "fabric-solve") as span:
+                report = fabric.solve(space, reducer=reducer,
+                                      verifier=verifier,
+                                      lease_cap=lease_cap,
+                                      trace=((tr, tid) if tid is not None
+                                             else None))
+                span.attrs.update(leases=report.leases,
+                                  requeues=report.requeues,
+                                  workers_lost=report.workers_lost)
+            with span_or_null(tr, tid, "reduce") as span:
+                plan = self.planner.complete_solve(
+                    prep, reducer.finalize(),
+                    time.perf_counter() - started, scorer_fn,
+                    verify=self._make_verifier(ticket.verify,
+                                               ticket.tenant, trace_id=tid))
+                span.attrs.update(promotions=reducer.promotions,
+                                  dedup_hits=reducer.dedup_hits)
             with self._lock:
                 t = ticket.tenant
                 self.stats.bump("fabric_leases", report.leases, tenant=t)
@@ -1540,36 +1520,32 @@ class PlanService:
 
     def _run_shard(self, job: _ShardJob, ticket: PlanTicket) -> None:
         state = job.state
-        tr = self.tracer
-        tid = ticket.trace_id if tr is not None else None
-        t_eval = time.perf_counter()
+        tid = ticket.trace_id if self.tracer is not None else None
         try:
-            for ev in evaluate(job.shard, gate=state.reducer):
-                state.reducer.add(ev)
+            with span_or_null(self.tracer, tid, "shard-eval",
+                              units=len(job.shard)):
+                for ev in evaluate(job.shard, gate=state.reducer):
+                    state.reducer.add(ev)
         except BaseException as e:
             if state.fail(e):
                 self._finish(ticket, state.prep, error=e)
             return
         finally:
-            if tid is not None:
-                tr.record(tid, "shard-eval", t_eval, time.perf_counter(),
-                          units=len(job.shard))
             with self._lock:
                 self.stats.bump("shards_completed", tenant=ticket.tenant)
         if state.shard_finished():
             try:
                 red = state.reducer
-                t_red = time.perf_counter()
-                plan = self.planner.complete_solve(
-                    state.prep, red.finalize(),
-                    time.perf_counter() - state.started, state.scorer_fn,
-                    verify=self._make_verifier(state.ticket.verify,
-                                               state.ticket.tenant,
-                                               trace_id=tid))
-                if tid is not None:
-                    tr.record(tid, "reduce", t_red, time.perf_counter(),
-                              promotions=red.promotions,
-                              dedup_hits=red.dedup_hits)
+                with span_or_null(self.tracer, tid, "reduce") as span:
+                    plan = self.planner.complete_solve(
+                        state.prep, red.finalize(),
+                        time.perf_counter() - state.started,
+                        state.scorer_fn,
+                        verify=self._make_verifier(state.ticket.verify,
+                                                   state.ticket.tenant,
+                                                   trace_id=tid))
+                    span.attrs.update(promotions=red.promotions,
+                                      dedup_hits=red.dedup_hits)
                 with self._lock:
                     self.stats.bump("best_promotions", red.promotions,
                                     tenant=ticket.tenant)

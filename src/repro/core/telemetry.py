@@ -47,8 +47,7 @@ from .planner import register_scorer
 
 TELEMETRY_FORMAT = "measured-cost/v1"
 
-# ops that move table data (tick timings ride along but never feed
-# scheme-vs-scheme comparisons: a whole tick is not a gather)
+# ops that move table data: the ones scheme-vs-scheme comparisons read
 DATA_OPS = ("gather", "scatter")
 
 # roofline-prior overhead coefficients: a fan-in-F crossbar port costs
@@ -589,8 +588,8 @@ class ServiceTelemetry:
 
     # -- observation -----------------------------------------------------------
     def observe(self, art, op: str, shape, seconds: float) -> None:
-        """One timed call (the artifact hooks and ``Server.tick`` both
-        land here).  Log it, then run the flush / refresh / demote checks
+        """One timed call (the artifact's gather/scatter hooks land
+        here).  Log it, then run the flush / refresh / demote checks
         outside the log lock."""
         self.log.observe_artifact(art, op, shape, seconds)
         with self._lock:

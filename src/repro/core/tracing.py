@@ -29,6 +29,17 @@ through:
   JSON), and ``/stats`` (registry snapshot) for ``launch/serve.py
   --metrics-port``.
 
+Live spans (``Tracer.span``) keep a thread-local stack: a span opened
+inside another takes it as its parent, and each JAX compile phase that
+runs while a live span is the innermost open one on its thread is added
+to that span's attrs (``trace_s``, ``lower_s``, ``compile_s``,
+``compiles``, ``cache_reads`` and their sum ``build_s``).  Once JAX is
+imported, a live span also opens a ``jax.profiler.TraceAnnotation`` of
+its own name, so it shows on a device trace's host plane, on the
+profiler's clock.  Tracing never imports JAX itself: fabric workers run
+without it.  :func:`serve_tracer` is the process's always-on tracer for
+the serve loop.
+
 Clock discipline: spans carry ``time.perf_counter()`` timestamps local
 to the recording process.  Worker-side spans travel as *relative*
 offsets from lease receipt and are re-based onto the driver's monotonic
@@ -42,6 +53,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -116,9 +128,14 @@ def spans_to_wire(spans: List[dict], base: float) -> List[dict]:
 
 class _NullSpan:
     """No-op stand-in so ``with tracer_or_none_span(...)`` sites stay
-    branch-free; never allocated per call."""
+    branch-free; never allocated per call.  Its ``attrs`` is a fresh
+    dict each time, so writes to it go nowhere."""
 
     __slots__ = ()
+
+    @property
+    def attrs(self) -> dict:
+        return {}
 
     def __enter__(self):
         return self
@@ -130,21 +147,129 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _LiveSpan:
-    """Context manager wrapper closing a span on exit."""
+def span_or_null(tracer: Optional["Tracer"], trace_id: Optional[str],
+                 name: str, **attrs):
+    """``tracer.span(trace_id, name, ...)``, or :data:`NULL_SPAN` when
+    there is no tracer or no trace."""
+    if tracer is None or trace_id is None:
+        return NULL_SPAN
+    return tracer.span(trace_id, name, **attrs)
 
-    __slots__ = ("tracer", "span")
+
+# JAX's compile phases, by monitoring event, and the attr each adds to
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_hits"
+
+_open = threading.local()          # .stack: the thread's open live spans
+_annotation = None                 # jax.profiler.TraceAnnotation, once hooked
+_hook_lock = threading.Lock()
+
+
+def _hook_jax():
+    """``TraceAnnotation`` once JAX is imported in this process, after
+    registering the process's one pair of compile listeners; ``None``
+    while JAX is not imported."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        with _hook_lock:
+            if _annotation is None:
+                import jax
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_duration)
+                jax.monitoring.register_event_listener(_on_event)
+                _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def _innermost() -> Optional["_LiveSpan"]:
+    stack = getattr(_open, "stack", None)
+    return stack[-1] if stack else None
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    key = _COMPILE_PHASES.get(event)
+    if key is not None:
+        live = _innermost()
+        if live is not None:
+            live.add_phase(key, float(duration))
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_READ_EVENT:
+        live = _innermost()
+        if live is not None:
+            live.span.attrs["cache_reads"] = \
+                live.span.attrs.get("cache_reads", 0) + 1
+            if live.tracer.metrics is not None:
+                live.tracer.metrics.inc("compile_cache_reads")
+
+
+class _LiveSpan:
+    """A span open in a ``with`` block: the thread's innermost open span
+    while inside, closed (and recorded) on exit."""
+
+    __slots__ = ("tracer", "span", "_annotation", "_phases")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self.tracer = tracer
         self.span = span
+        self._annotation = None
+        self._phases: Optional[List[Tuple[float, float]]] = None
 
     def __enter__(self) -> Span:
+        cls = _annotation or _hook_jax()
+        if cls is not None:
+            self._annotation = cls(self.span.name)
+            self._annotation.__enter__()
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        stack.append(self)
         return self.span
 
     def __exit__(self, *exc):
-        self.tracer.end(self.span)
+        end = time.perf_counter()
+        stack = _open.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:                           # closed out of order
+            stack.remove(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        span = self.span
+        if self._phases is not None:
+            attrs = span.attrs
+            attrs["build_s"] = min(
+                attrs.get("trace_s", 0.0) + attrs.get("lower_s", 0.0)
+                + attrs.get("compile_s", 0.0), end - span.start)
+        span.end = end
+        self.tracer._admit(span)
         return False
+
+    def add_phase(self, key: str, seconds: float) -> None:
+        """Add one compile phase that just ended.  A phase that ran inside
+        it (a compile while tracing) was added already: only the rest of
+        this one's time is."""
+        end = time.perf_counter()
+        start = end - seconds
+        phases = self._phases
+        if phases is None:
+            phases = self._phases = []
+        inner = 0.0
+        while phases and phases[-1][0] >= start:
+            a, b = phases.pop()
+            inner += b - a
+        phases.append((start, end))
+        attrs = self.span.attrs
+        attrs[key] = attrs.get(key, 0.0) + max(0.0, seconds - inner)
+        if key == "compile_s":
+            attrs["compiles"] = attrs.get("compiles", 0) + 1
+            if self.tracer.metrics is not None:
+                self.tracer.metrics.inc("compiles")
 
 
 # ---------------------------------------------------------------------------
@@ -313,7 +438,9 @@ class Tracer:
     The service holds ``tracer = None`` until ``enable_tracing()``;
     every hook site guards with that check, so the disabled cost is one
     attribute load.  Enabled, a span is two ``perf_counter`` calls, a
-    small object, and one lock-guarded list append."""
+    small object, and one lock-guarded list append.  Every closed span
+    named ``serve.*`` also feeds the histogram ``<name, "." -> "_">_ms``
+    of ``metrics``."""
 
     def __init__(self, *, recorder: Optional[FlightRecorder] = None,
                  metrics: Optional["MetricsRegistry"] = None,
@@ -325,6 +452,7 @@ class Tracer:
         self._spans: Dict[str, List[Span]] = {}
         self._dropped: Dict[str, int] = {}
         self._labels: Dict[str, str] = {}
+        _hook_jax()
 
     # -- recording ------------------------------------------------------------
     def begin(self, trace_id: str, name: str, *,
@@ -343,16 +471,26 @@ class Tracer:
 
     def span(self, trace_id: str, name: str, *,
              parent: Optional[Span] = None, **attrs) -> _LiveSpan:
-        """``with tracer.span(tid, "solve") as s: ...`` -- the span
-        closes (and records) on exit."""
-        return _LiveSpan(self, self.begin(trace_id, name, parent=parent,
-                                          **attrs))
+        """``with tracer.span(tid, "solve") as s: ...`` -- a live span.
+        It closes (and records) on exit; without ``parent`` its parent is
+        the thread's innermost open live span of the same trace; it shows
+        in a profiler trace under its name; and it carries the compile
+        phases that run while it is the innermost open span."""
+        if parent is None:
+            live = _innermost()
+            if live is not None and live.span.trace_id == trace_id:
+                parent = live.span
+        return _LiveSpan(self, Span(
+            trace_id, name,
+            parent_id=parent.span_id if parent is not None else None,
+            attrs=attrs))
 
     def record(self, trace_id: str, name: str, start: float, end: float,
                *, parent: Optional[Span] = None, origin: str = "driver",
                **attrs) -> Span:
-        """Record an already-timed stage retroactively (how the queue
-        wait -- measured by timestamps, not an open span -- lands)."""
+        """Record an already-timed stage retroactively, for stretches no
+        live span can cover: a queue wait or a lease, measured by
+        timestamps, and a fabric worker's re-based spans."""
         span = Span(trace_id, name,
                     parent_id=parent.span_id if parent is not None else None,
                     start=start, origin=origin, attrs=attrs or None)
@@ -388,6 +526,9 @@ class Tracer:
         return n
 
     def _admit(self, span: Span) -> None:
+        if self.metrics is not None and span.name.startswith("serve."):
+            self.metrics.observe(span.name.replace(".", "_") + "_ms",
+                                 (span.end - span.start) * 1e3)
         with self._lock:
             spans = self._spans.setdefault(span.trace_id, [])
             if len(spans) >= self.max_spans_per_trace:
@@ -452,6 +593,26 @@ class Tracer:
             self.recorder.note_anomaly(kind, detail)
 
 
+SERVE_TRACES = 16    # rolled serve-loop traces the serve tracer keeps
+_serve: Optional[Tracer] = None
+_serve_lock = threading.Lock()
+
+
+def serve_tracer() -> Tracer:
+    """The process's serve-loop tracer, always on: ``Server`` records
+    through it when its plan service has no tracer of its own.  Its
+    registry holds the ``serve_*`` histograms and gauges and the
+    ``compiles`` / ``compile_cache_reads`` counters; its flight recorder
+    keeps the last :data:`SERVE_TRACES` rolled serve traces."""
+    global _serve
+    if _serve is None:
+        with _serve_lock:
+            if _serve is None:
+                _serve = Tracer(recorder=FlightRecorder(SERVE_TRACES),
+                                metrics=MetricsRegistry())
+    return _serve
+
+
 # ---------------------------------------------------------------------------
 # Metrics registry
 # ---------------------------------------------------------------------------
@@ -502,6 +663,8 @@ class _Histogram:
 
 
 def _label_key(labels: dict) -> Tuple[Tuple[str, str], ...]:
+    if not labels:
+        return ()
     return tuple(sorted((str(k), str(v)) for k, v in labels.items()))
 
 
@@ -671,6 +834,8 @@ __all__ = [
     "Tracer",
     "chrome_trace_events",
     "new_trace_id",
+    "serve_tracer",
+    "span_or_null",
     "spans_to_wire",
     "start_observability_server",
 ]

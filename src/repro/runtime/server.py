@@ -28,6 +28,17 @@ construction.  Writes go the same way: token records queue per tick and
 flush through **one batched banked scatter** (``artifact.scatter`` with
 per-slot column indices), so the resolution circuit -- not host-side
 index math -- places the rows on both paths.
+
+Every tick runs in live ``serve.*`` spans (:mod:`repro.core.tracing`),
+recorded through the plan service's tracer when it has one and the
+process's :func:`~repro.core.tracing.serve_tracer` otherwise, and shown
+on a profiler trace's host plane: ``serve.tick`` holds ``serve.swap``
+(a layout adopted), ``serve.admit`` (one request's prefill),
+``serve.gather`` (with ``serve.gather.wait``, the blocking readback),
+``serve.inputs``, ``serve.step`` (with ``serve.step.wait``),
+``serve.emit`` and ``serve.scatter`` (every record flush).  Compiles
+land on the innermost span that ran them; ``serve.queue_wait`` records
+each request's time from ``submit`` to admission.
 """
 
 from __future__ import annotations
@@ -48,6 +59,7 @@ from ..core.jointplan import ResourceBudget
 from ..core.service import (JointTicket, PlanService, PlanTicket,
                             default_service)
 from ..core.polytope import Affine, MemorySpec
+from ..core.tracing import new_trace_id, serve_tracer
 from ..models import Model
 from ..launch import steps as steps_mod
 
@@ -59,6 +71,7 @@ class Request:
     max_new: int = 16
     out: List[int] = field(default_factory=list)
     done: bool = False
+    queued_at: Optional[float] = None   # perf_counter at submit
 
 
 def _page_program(max_len: int, page: int, readers: int) -> Program:
@@ -336,12 +349,12 @@ class Server:
         self.tokens = jnp.zeros((max_batch, 1), jnp.int32)
         self.positions = np.zeros(max_batch, np.int64)  # next record slot
         self.ticks = 0
-        # rolling serve trace (only when the answering service has
-        # tracing on): gather/decode/scatter/promote spans accumulate
-        # under one trace_id, finished + restarted every
+        # rolling serve trace: (tracer, trace_id) the serve.* spans
+        # accumulate under, finished and restarted every
         # _SERVE_TRACE_TICKS ticks so completed windows reach the
         # flight recorder instead of growing forever
-        self._serve_trace: Optional[str] = None
+        self._trace: Optional[tuple] = None
+        self._trace_ticks = 0
 
     # -- banked token records ----------------------------------------------------
     def _adopt_kv_artifact(self, art: CompiledBankingPlan,
@@ -370,12 +383,13 @@ class Server:
         map; no host-side bank arithmetic."""
         if not self._pending_records:
             return
-        pend, self._pending_records = self._pending_records, []
-        rows = np.asarray([p for p, _, _ in pend], np.int64)
-        cols = np.asarray([s for _, s, _ in pend], np.int64)
-        vals = np.asarray([t for _, _, t in pend], np.int32)
-        self.kv_records = self._kv_art.scatter(self.kv_records, rows, vals,
-                                               col=cols)
+        with self._span("serve.scatter"):
+            pend, self._pending_records = self._pending_records, []
+            rows = np.asarray([p for p, _, _ in pend], np.int64)
+            cols = np.asarray([s for _, s, _ in pend], np.int64)
+            vals = np.asarray([t for _, _, t in pend], np.int32)
+            self.kv_records = self._kv_art.scatter(self.kv_records, rows,
+                                                   vals, col=cols)
 
     def _gather_next_tokens(self) -> Dict[int, int]:
         """Each active slot's decode input, via ONE batched banked gather.
@@ -393,7 +407,8 @@ class Server:
             pos = min(int(self.positions[s]), self.max_len)
             rows[i] = np.clip(np.arange(pos - W, pos), 0, self.max_len - 1)
         got = self._kv_art.gather(self.kv_records, jnp.asarray(rows))
-        got = np.asarray(got)                      # (slots, W, max_batch)
+        with self._span("serve.gather.wait"):
+            got = np.asarray(got)                  # (slots, W, max_batch)
         layout = self._kv_art.describe()
         out = {}
         for i, s in enumerate(slots):
@@ -445,26 +460,28 @@ class Server:
                     self._kv_best_version = 0
         if t is None:
             return
-        if t.done():
+        final = t.done()
+        if final:
             self._kv_ticket = None
             try:
                 art = t.artifact()
             except Exception:
                 return  # solve failed: keep serving the current layout
-            if art.layout == self._kv_art.layout:
-                return  # a promotion already landed the winning layout
-            self._swap_to(art)
-            self.swaps += 1
-            return
-        version = t.best_version()
-        if version == self._kv_best_version:
-            return
-        self._kv_best_version = version
-        art = t.best_so_far_artifact()
+        else:
+            version = t.best_version()
+            if version == self._kv_best_version:
+                return
+            self._kv_best_version = version
+            art = t.best_so_far_artifact()
+        # a promotion may already have landed the winning layout
         if art is None or art.layout == self._kv_art.layout:
             return
-        self._swap_to(art)
-        self.promotions += 1
+        with self._span("serve.swap", layout=art.describe()):
+            self._swap_to(art)
+        if final:
+            self.swaps += 1
+        else:
+            self.promotions += 1
 
     # -- coherent multi-pool swap ---------------------------------------------
     @property
@@ -481,19 +498,21 @@ class Server:
         no tick ever reads pools from two generations.  Returns how
         many pools actually changed layout."""
         changed = 0
-        kv = arts.get("kv_pool")
-        if kv is not None and self._kv_art is not None \
-                and kv.layout != self._kv_art.layout:
-            self._swap_to(kv)
-            changed += 1
-        for name, pool in self.pools.items():
-            a = arts.get(name)
-            if a is not None and a.layout != pool.artifact.layout:
-                pool.swap(a)
+        with self._span("serve.swap") as span:
+            kv = arts.get("kv_pool")
+            if kv is not None and self._kv_art is not None \
+                    and kv.layout != self._kv_art.layout:
+                self._swap_to(kv)
                 changed += 1
-        gen = max(self.generations.values(), default=0) + 1
-        for name in self.generations:
-            self.generations[name] = gen
+            for name, pool in self.pools.items():
+                a = arts.get(name)
+                if a is not None and a.layout != pool.artifact.layout:
+                    pool.swap(a)
+                    changed += 1
+            gen = max(self.generations.values(), default=0) + 1
+            for name in self.generations:
+                self.generations[name] = gen
+            span.attrs.update(generation=gen, changed=changed)
         return changed
 
     def _maybe_swap_joint(self) -> None:
@@ -532,6 +551,7 @@ class Server:
 
     # -- admission -------------------------------------------------------------
     def submit(self, req: Request):
+        req.queued_at = time.perf_counter()
         self.queue.append(req)
 
     def _admit(self):
@@ -548,135 +568,119 @@ class Server:
                     continue
                 self.pager.try_alloc(slot, need_tokens)
             self.queue.popleft()
-            self.positions[slot] = 0
-            # per-request prefill: run the prompt through decode one token at
-            # a time into this slot (batch=1 prefill folded into the shared
-            # cache; a production server runs a separate prefill graph)
-            toks = req.prompt
-            for t in toks:
-                self.tokens = self.tokens.at[slot, 0].set(int(t))
-                nxt, _, self.cache = self._decode(
-                    self._params, self.cache, self.tokens)
-                self._record(slot, int(t))
-            nxt_tok = int(np.asarray(nxt)[slot, 0])
-            req._next = nxt_tok
-            self._record(slot, nxt_tok)   # the next tick's decode input
+            tr, tid = self._tracer()
+            if req.queued_at is not None:
+                tr.record(tid, "serve.queue_wait", req.queued_at,
+                          time.perf_counter(), uid=req.uid)
+            with tr.span(tid, "serve.admit", uid=req.uid, slot=slot,
+                         prompt_tokens=len(req.prompt)):
+                self.positions[slot] = 0
+                # per-request prefill: run the prompt through decode one
+                # token at a time into this slot (batch=1 prefill folded
+                # into the shared cache; a production server runs a
+                # separate prefill graph)
+                for t in req.prompt:
+                    self.tokens = self.tokens.at[slot, 0].set(int(t))
+                    nxt, _, self.cache = self._decode(
+                        self._params, self.cache, self.tokens)
+                    self._record(slot, int(t))
+                nxt_tok = int(np.asarray(nxt)[slot, 0])
+                req._next = nxt_tok
+                self._record(slot, nxt_tok)   # the next tick's decode input
             self.active[slot] = req
 
     # -- decode tick -------------------------------------------------------------
-    def tick(self):
-        """One decode tick.  When the KV plan's service has telemetry
-        enabled, ticks that decoded (active slots) are wall-timed and
-        logged as ``op="tick"`` observations against the serving
-        artifact -- end-to-end evidence alongside the per-call
-        gather/scatter hooks."""
-        hub = getattr(self._kv_service, "telemetry", None)
-        art = self._kv_art
-        if hub is None or art is None or not art.signature:
-            self._tick()
-            return
-        before = self.ticks
-        t0 = time.perf_counter()
-        self._tick()
-        if self.ticks > before:   # idle calls (nothing active) don't count
-            hub.observe(art, "tick", (self.max_batch,),
-                        time.perf_counter() - t0)
-
     _SERVE_TRACE_TICKS = 256   # ticks per rolling serve-trace window
 
-    def _serve_tracer(self):
-        """(tracer, serve trace_id) off the answering service, or
-        (None, None) -- the serve loop traces only when the plan
-        service does."""
-        tr = getattr(self._kv_service, "tracer", None)
-        if tr is None:
-            return None, None
-        tid = self._serve_trace
-        if tid is None:
-            from ..core.tracing import new_trace_id
-            tid = self._serve_trace = new_trace_id()
-            tr.label(tid, "serve loop")
-        return tr, tid
+    def _tracer(self):
+        """(tracer, trace_id) of the rolling serve trace: the answering
+        plan service's tracer when it has one, else the process's
+        ``serve_tracer()``."""
+        tr = getattr(self._kv_service, "tracer", None) or serve_tracer()
+        cur = self._trace
+        if cur is None or cur[0] is not tr:
+            self._end_trace()
+            cur = self._trace = (tr, new_trace_id())
+            tr.label(cur[1], "serve loop")
+        return cur
 
-    def _tick(self):
-        tr, tid = self._serve_tracer()
-        metrics = getattr(self._kv_service, "metrics", None)
-        t_tick = time.perf_counter()
-        swaps0 = self.swaps + self.promotions \
-            + self.joint_swaps + self.joint_promotions
+    def _span(self, name: str, **attrs):
+        tr, tid = self._tracer()
+        return tr.span(tid, name, **attrs)
+
+    def _end_trace(self) -> None:
+        """Finish the rolling serve trace: it reaches the flight
+        recorder, and the next span starts a fresh one."""
+        if self._trace is not None:
+            tr, tid = self._trace
+            self._trace = None
+            self._trace_ticks = 0
+            tr.finish(tid, status="ok")
+
+    def _maybe_swap(self) -> None:
         if self._joint is not None:
             self._maybe_swap_joint()
         else:
             self._maybe_swap_kv()
-        if tr is not None and self.swaps + self.promotions \
-                + self.joint_swaps + self.joint_promotions > swaps0:
-            tr.record(tid, "promote", t_tick, time.perf_counter(),
-                      swaps=self.swaps, promotions=self.promotions,
-                      joint_swaps=self.joint_swaps,
-                      joint_promotions=self.joint_promotions)
-        self._admit()
-        if not self.active:
+
+    def tick(self):
+        """One decode tick: adopt a layout that landed, admit queued
+        requests into free slots, then decode one token for every active
+        slot, all inside one ``serve.tick`` span.  With nothing queued
+        or active a call only adopts."""
+        if not (self.queue or self.active):
+            self._maybe_swap()
             return
+        tr, tid = self._tracer()
+        with tr.span(tid, "serve.tick", tick=self.ticks) as tick_span:
+            self._maybe_swap()
+            self._admit()
+            if self.active:
+                self._decode_active(tr, tid, tick_span)
+        if tr.metrics is not None:
+            tr.metrics.set_gauge("serve_active_slots", len(self.active))
+            tr.metrics.set_gauge("serve_queue_depth", len(self.queue))
+        self._trace_ticks += 1
+        if self._trace_ticks >= self._SERVE_TRACE_TICKS:
+            self._end_trace()
+
+    def _decode_active(self, tr, tid, tick_span) -> None:
         if self.kv_records is not None:
-            t_g = time.perf_counter()
-            nxt_in = self._gather_next_tokens()   # one batched banked gather
-            t_g_end = time.perf_counter()
-            if tr is not None:
-                tr.record(tid, "gather", t_g, t_g_end,
-                          slots=len(self.active))
-            if metrics is not None:
-                metrics.observe("serve_gather_ms", (t_g_end - t_g) * 1e3)
+            with tr.span(tid, "serve.gather"):
+                nxt_in = self._gather_next_tokens()  # one batched gather
         else:
             nxt_in = {s: getattr(r, "_next", 1)
                       for s, r in self.active.items()}
-        for slot in self.active:
-            self.tokens = self.tokens.at[slot, 0].set(nxt_in[slot])
-        t_d = time.perf_counter()
-        nxt, _, self.cache = self._decode(self._params, self.cache,
-                                          self.tokens)
-        nxt = np.asarray(nxt)
-        if tr is not None:
-            tr.record(tid, "decode", t_d, time.perf_counter(),
-                      slots=len(self.active))
-        finished = []
-        for slot, req in self.active.items():
-            tok = int(nxt[slot, 0])
-            req.out.append(tok)
-            req._next = tok
-            self._record(slot, tok)
-            if len(req.out) >= req.max_new:
-                req.done = True
-                finished.append(slot)
-        for slot in finished:
-            del self.active[slot]
-            if self.pager is not None:
-                self.pager.release(slot)
+        with tr.span(tid, "serve.inputs"):
+            for slot in self.active:
+                self.tokens = self.tokens.at[slot, 0].set(nxt_in[slot])
+        with tr.span(tid, "serve.step"):
+            nxt, _, self.cache = self._decode(self._params, self.cache,
+                                              self.tokens)
+            with tr.span(tid, "serve.step.wait"):
+                nxt = np.asarray(nxt)
+        slots = len(self.active)
+        with tr.span(tid, "serve.emit"):
+            finished = []
+            for slot, req in self.active.items():
+                tok = int(nxt[slot, 0])
+                req.out.append(tok)
+                req._next = tok
+                self._record(slot, tok)
+                if len(req.out) >= req.max_new:
+                    req.done = True
+                    finished.append(slot)
+            for slot in finished:
+                del self.active[slot]
+                if self.pager is not None:
+                    self.pager.release(slot)
         if self.kv_records is not None:
-            t_s = time.perf_counter()
             self._flush_records()   # this tick's records land this tick
-            t_s_end = time.perf_counter()
-            if tr is not None:
-                tr.record(tid, "scatter", t_s, t_s_end)
-            if metrics is not None:
-                metrics.observe("serve_scatter_ms",
-                                (t_s_end - t_s) * 1e3)
         self.ticks += 1
-        if metrics is not None:
-            metrics.observe("serve_tick_ms",
-                            (time.perf_counter() - t_tick) * 1e3)
-        if tr is not None and self.ticks % self._SERVE_TRACE_TICKS == 0:
-            # roll the window: the finished trace reaches the flight
-            # recorder; the next tick starts a fresh trace_id
-            tr.finish(tid, status="ok")
-            self._serve_trace = None
+        tick_span.attrs.update(slots=slots, tokens=slots)
 
     def run(self, max_ticks: int = 1000):
         while (self.queue or self.active) and self.ticks < max_ticks:
             self.tick()
-        # flush a partial serve-trace window so short runs still land
-        # their gather/decode/scatter/promote spans in the recorder
-        if self._serve_trace is not None:
-            tr = getattr(self._kv_service, "tracer", None)
-            if tr is not None:
-                tr.finish(self._serve_trace, status="ok")
-            self._serve_trace = None
+        # a partial serve-trace window still lands in the recorder
+        self._end_trace()

@@ -459,13 +459,13 @@ def test_server_swaps_all_pools_coherently(monkeypatch):
     assert server.coherent and set(server.generations.values()) == {0}
 
     # every tick must observe a single generation across all pools
-    orig_tick = server._tick
+    orig_tick = server.tick
 
     def checked_tick():
         assert server.coherent, f"mixed generations: {server.generations}"
         orig_tick()
 
-    server._tick = checked_tick
+    server.tick = checked_tick
     rng = np.random.default_rng(0)
     r0 = Request(uid=0, prompt=rng.integers(
         2, cfg.vocab - 1, size=3).astype(np.int32), max_new=2)

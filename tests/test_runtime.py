@@ -1,5 +1,5 @@
 """Fault tolerance: checkpoint/restart, bitwise resume, elastic re-shard,
-straggler detection, data determinism."""
+straggler detection, data determinism; the serve loop's spans."""
 
 import os
 
@@ -142,3 +142,124 @@ def test_compressed_psum_error_feedback():
         drift.append(np.abs(residual).max())
     # error feedback keeps the residual bounded by one quantization step
     assert drift[-1] <= float(np.abs(x).max() / 127.0 * 2)
+
+
+# -- the serve loop's spans ------------------------------------------------------
+
+
+def _tiny_server(service, max_new=8):
+    """A tiny dense server on its solved KV layout, two requests queued."""
+    import dataclasses
+
+    from repro.runtime.server import Request, Server, page_ticket
+    cfg = dataclasses.replace(_tiny(), n_layers=1, d_model=32, d_ff=64,
+                              vocab=64, head_dim=16)
+    ticket = page_ticket(cfg, 32, page=8, readers=2, service=service)
+    assert ticket.wait(120)
+    server = Server(get_model(cfg), max_batch=2, max_len=32, kv_plan=ticket)
+    for uid in range(2):
+        server.submit(Request(uid=uid, prompt=np.arange(2, 5 + uid,
+                                                        dtype=np.int32),
+                              max_new=max_new))
+    return server
+
+
+@pytest.fixture(scope="module")
+def first_tick():
+    """The spans of a tiny server's first tick, recorded through the
+    process's serve tracer (the plan service has none)."""
+    from repro.core.service import PlanService
+    from repro.core.tracing import serve_tracer
+    svc = PlanService(workers=1)
+    server = _tiny_server(svc)
+    server.tick()
+    tr, tid = server._tracer()
+    assert tr is serve_tracer()
+    yield server, tr.spans(tid)
+    svc.shutdown()
+
+
+def test_one_tick_gives_the_serve_span_tree(first_tick):
+    _, spans = first_tick
+    by_id = {s.span_id: s for s in spans}
+    edges = {(s.name, by_id[s.parent_id].name if s.parent_id else None)
+             for s in spans}
+    assert edges == {
+        ("serve.tick", None), ("serve.queue_wait", None),
+        ("serve.admit", "serve.tick"), ("serve.gather", "serve.tick"),
+        ("serve.scatter", "serve.gather"),
+        ("serve.gather.wait", "serve.gather"),
+        ("serve.inputs", "serve.tick"), ("serve.step", "serve.tick"),
+        ("serve.step.wait", "serve.step"), ("serve.emit", "serve.tick"),
+        ("serve.scatter", "serve.tick")}
+    for s in spans:
+        if s.parent_id:
+            p = by_id[s.parent_id]
+            assert p.start <= s.start <= s.end <= p.end, s.name
+    tick = next(s for s in spans if s.name == "serve.tick")
+    assert (tick.attrs["tick"], tick.attrs["slots"],
+            tick.attrs["tokens"]) == (0, 2, 2)
+    admits = sorted((s.attrs["uid"], s.attrs["slot"],
+                     s.attrs["prompt_tokens"])
+                    for s in spans if s.name == "serve.admit")
+    assert admits == [(0, 0, 3), (1, 1, 4)]
+    assert sorted(s.attrs["uid"] for s in spans
+                  if s.name == "serve.queue_wait") == [0, 1]
+
+
+def test_a_tick_s_compiles_land_on_the_banked_call_that_ran_them(
+        first_tick):
+    _, spans = first_tick
+    tick = next(s for s in spans if s.name == "serve.tick")
+    assert "compiles" not in tick.attrs and "build_s" not in tick.attrs
+    banked = [s for s in spans if s.name in ("serve.gather", "serve.scatter")
+              and s.attrs.get("compiles", 0) >= 1]
+    assert banked
+    for s in banked:
+        assert 0 < s.attrs["build_s"] <= s.end - s.start
+        assert s.attrs["build_s"] == pytest.approx(
+            s.attrs.get("trace_s", 0) + s.attrs.get("lower_s", 0)
+            + s.attrs["compile_s"])
+
+
+def test_serve_spans_are_on_the_profiler_host_plane(first_tick, tmp_path):
+    server, _ = first_tick
+    with jax.profiler.trace(str(tmp_path)):
+        server.tick()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    names = {e.name for plane in pd.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"serve.tick", "serve.gather", "serve.step.wait"} <= names
+
+
+def test_serve_metrics_reach_the_service_endpoint_without_tick_telemetry():
+    """A plan service with tracing and telemetry on: the serve loop
+    records through the service's tracer, so ``serve_tick_ms`` reaches
+    its ``/metrics``; the telemetry hub logs gathers and scatters only."""
+    import urllib.request
+
+    from repro.core.service import PlanService
+    from repro.core.tracing import start_observability_server
+    svc = PlanService(workers=1)
+    svc.enable_tracing()
+    hub = svc.enable_telemetry()
+    server = _tiny_server(svc, max_new=2)
+    server.run(max_ticks=4)
+    assert server.ticks >= 2
+    http = start_observability_server(svc.metrics, svc.recorder,
+                                      tracer=svc.tracer, port=0)
+    try:
+        host, port = http.server_address[:2]
+        body = urllib.request.urlopen(
+            f"http://{host}:{port}/metrics", timeout=10).read().decode()
+    finally:
+        http.shutdown()
+        svc.shutdown()
+    for name in ("serve_tick_ms_count", "serve_gather_ms_count",
+                 "serve_scatter_ms_count", "serve_queue_wait_ms_count",
+                 "serve_active_slots", "serve_queue_depth", "compiles"):
+        assert name in body, name
+    ops = {r.op for r in hub.log.records()}
+    assert "gather" in ops and "tick" not in ops

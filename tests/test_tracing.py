@@ -224,6 +224,84 @@ def test_remote_span_rebasing():
     assert span.attrs["evaluated"] == 5 and span.attrs["clock"] == "rebased"
 
 
+def test_live_spans_nest_on_their_own_thread():
+    tr = Tracer()
+    tid = new_trace_id()
+    seen = {}
+
+    def other():
+        with tr.span(tid, "elsewhere") as s:
+            seen["other"] = s
+
+    with tr.span(tid, "outer") as outer:
+        with tr.span(tid, "inner") as inner:
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=30)
+        with tr.span(new_trace_id(), "another-trace") as foreign:
+            pass
+    assert not th.is_alive()
+    assert outer.parent_id is None
+    assert inner.parent_id == outer.span_id
+    assert seen["other"].parent_id is None
+    assert foreign.parent_id is None
+
+
+def test_compile_phases_land_on_the_innermost_live_span():
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+    tr = Tracer()
+    tid = new_trace_id()
+    with tr.span(tid, "outer") as outer:
+        with tr.span(tid, "inner") as inner:
+            jax.jit(lambda x: x * 3 + next(_UID))(jnp.arange(7.0))
+    assert inner.attrs["compiles"] >= 1
+    assert 0 < inner.attrs["build_s"] <= inner.end - inner.start
+    assert "compiles" not in outer.attrs and "build_s" not in outer.attrs
+
+
+def test_a_nested_compile_phase_counts_once():
+    jax = pytest.importorskip("jax")
+    record = jax.monitoring.record_event_duration_secs
+    tr = Tracer()
+    with tr.span(new_trace_id(), "build") as span:
+        time.sleep(0.06)
+        # a 20-ms compile that ran inside a 60-ms trace phase
+        record("/jax/core/compile/backend_compile_duration", 0.02)
+        time.sleep(0.01)
+        record("/jax/core/compile/jaxpr_trace_duration", 0.06)
+    assert span.attrs["compile_s"] == pytest.approx(0.02)
+    assert span.attrs["trace_s"] == pytest.approx(0.04)
+    assert span.attrs["build_s"] == pytest.approx(0.06)
+    assert span.attrs["compiles"] == 1
+
+
+def test_serve_spans_feed_histograms():
+    m = MetricsRegistry()
+    tr = Tracer(metrics=m)
+    tid = new_trace_id()
+    with tr.span(tid, "serve.gather"):
+        with tr.span(tid, "serve.gather.wait"):
+            pass
+    with tr.span(tid, "lint"):
+        pass
+    t0 = time.perf_counter()
+    tr.record(tid, "serve.queue_wait", t0 - 0.005, t0, uid=3)
+    assert m.histogram("serve_gather_ms")["count"] == 1
+    assert m.histogram("serve_gather_wait_ms")["count"] == 1
+    assert m.histogram("serve_queue_wait_ms")["max"] == pytest.approx(
+        5.0, rel=0.01)
+    assert m.histogram("lint_ms") is None
+
+
+def test_serve_tracer_is_one_bounded_tracer():
+    from repro.core.tracing import SERVE_TRACES, serve_tracer
+    tr = serve_tracer()
+    assert serve_tracer() is tr
+    assert tr.metrics is not None
+    assert tr.recorder.capacity == SERVE_TRACES
+
+
 # ---------------------------------------------------------------------------
 # Service integration
 # ---------------------------------------------------------------------------
