@@ -16,7 +16,9 @@ the per-slot record write run through Mosaic on both layouts.
 It fails (exit 1) unless JAX's first device is a TPU, the step fits the
 chip's memory, every request finishes, the solved layout lands, every
 token gathered through the banked table equals the token the host
-recorded, and every decode step's logits are finite.  The last line of
+recorded, every decode step's logits are finite, and nothing compiles
+after the first tick on the solved layout (each banked kernel is built
+once per layout and shape).  The last line of
 standard output is a JSON object naming the device.
 """
 
@@ -166,10 +168,16 @@ def main() -> None:
           "the first tick did not serve from the fallback layout")
     release.set()
     check(ticket.wait(SOLVE_TIMEOUT_S), "the KV plan's solve never landed")
+    server.tick()                 # adopts the solved layout, decodes on it
+    check(server.pager.artifact is not fallback,
+          "the second tick did not adopt the solved layout")
+    compiles1 = counters.counter("compiles")
+    ticks1 = server.ticks
     server.run(max_ticks=8 * NEW_TOKENS)
     wall = time.perf_counter() - t0
     compiles = counters.counter("compiles") - compiles0
     reads = counters.counter("compile_cache_reads") - reads0
+    later = counters.counter("compiles") - compiles1
 
     solved = server.pager.artifact
     tokens = sum(len(r.out) for r in requests)
@@ -182,8 +190,13 @@ def main() -> None:
     print(f"record checks per layout: {server.record_checks}; "
           f"mismatches={server.record_mismatches}", flush=True)
     print(f"compilations while serving: {compiles:g} "
-          f"({server.ticks} ticks); persistent cache reads while serving: "
-          f"{reads:g}", flush=True)
+          f"({server.ticks} ticks), {later:g} of them in the "
+          f"{server.ticks - ticks1} ticks after the first on the solved "
+          f"layout; persistent cache reads while serving: {reads:g}",
+          flush=True)
+    for name, art in (("fallback", fallback), ("solved", solved)):
+        print(f"banked kernels on the {name} layout: builds "
+              f"{art.kernel_builds}, calls {art.kernel_calls}", flush=True)
     peak = dev.memory_stats().get("peak_bytes_in_use")
     print(f"peak_bytes_in_use: {peak}", flush=True)
 
@@ -197,6 +210,8 @@ def main() -> None:
     check(server.record_checks.get(fallback.describe(), 0) > 0
           and server.record_checks.get(solved.describe(), 0) > 0,
           "the banked gather was not checked on both layouts")
+    check(later == 0, f"{later:g} compilations after the first tick on "
+          f"the solved layout: a kernel was built again")
     check(server.record_mismatches == 0,
           f"{server.record_mismatches} gathered tokens differ from the "
           f"tokens the host recorded")

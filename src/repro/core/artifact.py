@@ -20,6 +20,9 @@ durable :class:`CompiledBankingPlan` that owns everything execution needs:
   compiled resolution arithmetic addressing its row DMAs;
 * ``scatter(table, rows, values)`` -- the write path through the same
   circuit (full rows, or single columns for per-slot record writes);
+  each kernel is built once per input shapes and dtypes into a jitted
+  executable kept on the artifact (``kernel_builds`` / ``kernel_calls``
+  count the builds and the calls);
 * ``to_partition_spec(mesh_axes)`` mapping the banked dimensions onto mesh
   axes for device-level banking.
 
@@ -31,12 +34,14 @@ the compiled artifact is the only execution interface.
 
 from __future__ import annotations
 
+import functools
 import json
+import threading
 import time
 import warnings
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -54,6 +59,9 @@ from .transforms import (
 FORMAT = "compiled-banking-plan/v1"
 
 BACKENDS = ("jax", "numpy")
+
+# the banked kernels an artifact runs: ``kernels.banked_gather.banked_<op>``
+KERNELS = ("gather", "scatter", "scatter_elems")
 
 
 # ---------------------------------------------------------------------------
@@ -129,6 +137,19 @@ class BankingLayout:
         return (self.n_banks, self.bank_volume, row_width)
 
 
+def _split(addr, dims: Tuple[int, ...]):
+    """Flat row-major logical address -> per-dimension coordinates."""
+    if len(dims) == 1:
+        return (addr,)
+    strides = []
+    s = 1
+    for d in reversed(dims):
+        strides.append(s)
+        s *= d
+    strides = strides[::-1]
+    return tuple((addr // st) % d for st, d in zip(strides, dims))
+
+
 # ---------------------------------------------------------------------------
 # The compiled artifact
 # ---------------------------------------------------------------------------
@@ -167,6 +188,12 @@ class CompiledBankingPlan:
         self.note = note
         self._tables_cache: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self._telemetry = None   # opt-in timing sink (see enable_telemetry)
+        # jitted kernel executables by (op, interpret, input shapes and
+        # dtypes), built on first use; builds = cache misses per op
+        self._kernels: Dict[tuple, Callable] = {}
+        self._kernels_lock = threading.Lock()
+        self.kernel_builds = dict.fromkeys(KERNELS, 0)
+        self.kernel_calls = dict.fromkeys(KERNELS, 0)
         self._lower()
 
     # -- lowering ----------------------------------------------------------
@@ -195,6 +222,10 @@ class CompiledBankingPlan:
 
         self.ba = ba   # bank address from logical coordinates x0..x{n-1}
         self.bo = bo   # intra-bank offset from logical coordinates
+        # the same from a flat row-major address: the kernels' index map
+        dims = self.layout.dims
+        self._ba_addr = lambda addr: ba(*_split(addr, dims))
+        self._bo_addr = lambda addr: bo(*_split(addr, dims))
 
     # -- convenience metadata ----------------------------------------------
     @property
@@ -222,19 +253,6 @@ class CompiledBankingPlan:
         return f"<CompiledBankingPlan {self.describe()}>"
 
     # -- address resolution ------------------------------------------------
-    def _split(self, addr):
-        """Flat row-major logical address -> per-dimension coordinates."""
-        dims = self.layout.dims
-        if len(dims) == 1:
-            return (addr,)
-        strides = []
-        s = 1
-        for d in reversed(dims):
-            strides.append(s)
-            s *= d
-        strides = strides[::-1]
-        return tuple((addr // st) % d for st, d in zip(strides, dims))
-
     def resolve(self, addr):
         """(bank, offset) of a flat logical address (scalar or array).
 
@@ -242,7 +260,7 @@ class CompiledBankingPlan:
         transforms -- the same callables that address the gather kernel's
         row DMAs.
         """
-        xs = self._split(addr)
+        xs = _split(addr, self.layout.dims)
         return self.ba(*xs), self.bo(*xs)
 
     # -- layout conversion -------------------------------------------------
@@ -254,7 +272,7 @@ class CompiledBankingPlan:
             return self._tables_cache
         dims = self.layout.dims
         addr = np.arange(self.layout.logical_size, dtype=np.int64)
-        xs = self._split(addr)
+        xs = _split(addr, dims)
         g = self.geometry
         if self.kind == "flat":
             y = np.zeros_like(addr)
@@ -360,6 +378,40 @@ class CompiledBankingPlan:
                                                  col=col,
                                                  interpret=interpret))
 
+    def _kernel(self, op: str, interpret: bool, *args) -> Callable:
+        """The jitted executable of banked kernel ``op`` (one of
+        :data:`KERNELS`) for the shapes and dtypes of ``args``: built on
+        first use -- the kernel, the table's tile padding and the reshapes
+        around it in one executable -- then reused by every later call
+        with the same ones.  It lives on this artifact and dies with it."""
+        key = (op, interpret) + tuple((tuple(a.shape), np.dtype(a.dtype))
+                                      for a in args)
+        with self._kernels_lock:
+            fn = self._kernels.get(key)
+            if fn is None:
+                import jax
+
+                from ..kernels import banked_gather as kernels
+                kernel = functools.partial(
+                    getattr(kernels, f"banked_{op}"), ba_fn=self._ba_addr,
+                    bo_fn=self._bo_addr, interpret=interpret)
+                # a device trace names a Mosaic call after the jitted
+                # function that holds it; ``tpu_custom_call`` is the name
+                # it has outside a named jit, which trace readers match
+                kernel.__name__ = f"tpu_custom_call_banked_{op}"
+                fn = self._kernels[key] = jax.jit(kernel)
+                self.kernel_builds[op] += 1
+        return fn
+
+    def _run(self, op: str, interpret: Optional[bool], *args):
+        if interpret is None:
+            from ..kernels.ops import default_interpret
+            interpret = default_interpret()
+        fn = self._kernel(op, interpret, *args)
+        with self._kernels_lock:
+            self.kernel_calls[op] += 1
+        return fn(*args)
+
     def _gather(self, table, rows, *, interpret: Optional[bool] = None):
         """Gather logical rows from bank-major storage.
 
@@ -372,7 +424,8 @@ class CompiledBankingPlan:
         ``jax`` backend: binds the Pallas banked-gather kernel -- the
         compiled BA/BO arithmetic addresses each row DMA from the
         prefetched index, exactly where an FPGA would place the
-        resolution circuit.  ``interpret=None`` runs the kernel body
+        resolution circuit -- through the executable built for these
+        shapes (:meth:`_kernel`).  ``interpret=None`` runs the kernel body
         in the Pallas interpreter off the TPU
         (:func:`repro.kernels.ops.default_interpret`).
         ``numpy`` backend: direct advanced indexing through the same
@@ -383,28 +436,10 @@ class CompiledBankingPlan:
             # index arrays both work through one advanced-indexing gather
             ba, bo = self.resolve(np.asarray(rows, dtype=np.int64))
             return np.asarray(table)[ba, bo]
-        from ..kernels.banked_gather import banked_gather
-        from ..kernels.ops import default_interpret
-
-        if interpret is None:
-            interpret = default_interpret()
-
-        def ba_fn(addr):
-            return self.ba(*self._split(addr))
-
-        def bo_fn(addr):
-            return self.bo(*self._split(addr))
-
         import jax.numpy as jnp
-        rows = jnp.asarray(rows)
-        if rows.ndim == 2:
-            # stacked row-sets: flatten into a single grid so the whole
-            # batch is one pallas_call, then restore the (T, R) structure
-            T, R = rows.shape
-            flat = banked_gather(table, rows.reshape(T * R), ba_fn, bo_fn,
-                                 interpret=interpret)
-            return flat.reshape(T, R, flat.shape[-1])
-        return banked_gather(table, rows, ba_fn, bo_fn, interpret=interpret)
+
+        return self._run("gather", interpret, jnp.asarray(table),
+                         jnp.asarray(rows, jnp.int32))
 
     def _scatter(self, table, rows, values, *, col=None,
                  interpret: Optional[bool] = None):
@@ -421,9 +456,9 @@ class CompiledBankingPlan:
 
         ``jax`` backend: binds the Pallas banked-scatter kernel -- the
         compiled BA/BO arithmetic addresses each row DMA, in front of
-        the memory like the gather's.  ``numpy`` backend:
-        advanced-indexing assignment through the same compiled
-        resolution callables.
+        the memory like the gather's -- through the executable built for
+        these shapes.  ``numpy`` backend: advanced-indexing assignment
+        through the same compiled resolution callables.
         """
         if self.backend == "numpy":
             ba, bo = self.resolve(np.asarray(rows, dtype=np.int64))
@@ -433,27 +468,15 @@ class CompiledBankingPlan:
             else:
                 out[ba, bo, np.asarray(col, dtype=np.int64)] = values
             return out
-        from ..kernels.banked_gather import (banked_scatter,
-                                             banked_scatter_elems)
-        from ..kernels.ops import default_interpret
-
-        if interpret is None:
-            interpret = default_interpret()
-
-        def ba_fn(addr):
-            return self.ba(*self._split(addr))
-
-        def bo_fn(addr):
-            return self.bo(*self._split(addr))
-
         import jax.numpy as jnp
-        rows = jnp.asarray(rows)
-        values = jnp.asarray(values, dtype=table.dtype)
+
+        table = jnp.asarray(table)
+        rows = jnp.asarray(rows, jnp.int32)
+        values = jnp.asarray(values, table.dtype)
         if col is None:
-            return banked_scatter(table, rows, values, ba_fn, bo_fn,
-                                  interpret=interpret)
-        return banked_scatter_elems(table, rows, jnp.asarray(col), values,
-                                    ba_fn, bo_fn, interpret=interpret)
+            return self._run("scatter", interpret, table, rows, values)
+        return self._run("scatter_elems", interpret, table, rows,
+                         jnp.asarray(col, jnp.int32), values)
 
     # -- device-level banking ----------------------------------------------
     def banked_dims(self) -> Tuple[int, ...]:

@@ -20,7 +20,8 @@ Mosaic only DMAs whole (sublane, lane) tiles, so a row is moved as an
 ``(N, V, L, 128)``, padding ``D`` up to ``L * 128`` lanes with ``L`` a
 multiple of the dtype's sublane packing.  The view is free when ``D``
 already fills whole tiles (e.g. 128 int32 lanes); narrower tables are
-padded on the device around the call.
+padded on the device, inside the same executable as the kernel when the
+caller jits the wrapper (the artifact does, once per shape).
 
 This module is the *raw kernel only*: it takes the already-compiled
 ``ba_fn`` / ``bo_fn`` resolution callables.  Lowering a banking scheme to
@@ -80,14 +81,17 @@ def banked_gather(table: jax.Array, indices: jax.Array,
                   ba_fn: Callable, bo_fn: Callable, *,
                   interpret=False) -> jax.Array:
     """table: (N_banks, bank_volume, D) bank-major storage.
-    indices: (T,) int32 flat logical addresses.
-    Returns (T, D) gathered rows.
+    indices: flat logical addresses, a (T,) vector or a stacked (T, R)
+    matrix of row-sets, flattened into one grid.
+    Returns ``indices.shape + (D,)`` gathered rows.
 
     The bank-resolution arithmetic (ba_fn/bo_fn, the compiled artifact's
     transformed op graphs) runs in the kernel on the prefetched index
     scalars and addresses one row DMA per index, up to
     ``MAX_ROWS_PER_STEP`` in flight per grid step.
     """
+    lead = indices.shape
+    indices = indices.reshape(-1)
     T = indices.shape[0]
     D = table.shape[-1]
     tiles = _to_tiles(table)
@@ -104,7 +108,7 @@ def banked_gather(table: jax.Array, indices: jax.Array,
         out_shape=jax.ShapeDtypeStruct((T,) + tiles.shape[2:], table.dtype),
         interpret=interpret,
     )(indices.astype(jnp.int32), tiles)
-    return _from_tiles(out, D)
+    return _from_tiles(out, D).reshape(*lead, D)
 
 
 def _scatter_kernel(ba_fn, bo_fn, idx_ref, v_ref, t_in, t_ref, sem):

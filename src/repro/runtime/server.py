@@ -20,14 +20,15 @@ bank-major token-record table -- to the solved artifact between decode
 ticks once the background solve lands.
 
 Each decode tick reads its per-slot token records through **one batched
-banked gather** (a single ``pallas_call`` over a stacked ``(slots, W)``
-index matrix) instead of one kernel launch per row-set -- the compiled
-resolution arithmetic addresses the kernel's row DMAs from the
-prefetched indices either way, so the scheduler and the gather agree on the layout by
-construction.  Writes go the same way: token records queue per tick and
-flush through **one batched banked scatter** (``artifact.scatter`` with
-per-slot column indices), so the resolution circuit -- not host-side
-index math -- places the rows on both paths.
+banked gather** (a single ``pallas_call`` over a stacked
+``(max_batch, W)`` index matrix) instead of one kernel launch per
+row-set -- the compiled resolution arithmetic addresses the kernel's row
+DMAs from the prefetched indices either way, so the scheduler and the
+gather agree on the layout by construction.  Writes go the same way:
+token records queue per tick and flush through **one batched banked
+scatter** (``artifact.scatter`` with per-slot column indices), so the
+resolution circuit -- not host-side index math -- places the rows on
+both paths.
 
 Every tick runs in live ``serve.*`` spans (:mod:`repro.core.tracing`),
 recorded through the plan service's tracer when it has one and the
@@ -38,7 +39,9 @@ on a profiler trace's host plane: ``serve.tick`` holds ``serve.swap``
 ``serve.inputs``, ``serve.step`` (with ``serve.step.wait``),
 ``serve.emit`` and ``serve.scatter`` (every record flush).  Compiles
 land on the innermost span that ran them; ``serve.queue_wait`` records
-each request's time from ``submit`` to admission.
+each request's time from ``submit`` to admission.  Once a tick the
+served artifact's kernel builds and calls are published as the gauges
+``banked_kernel_builds`` and ``banked_kernel_calls`` (label ``op``).
 """
 
 from __future__ import annotations
@@ -380,11 +383,19 @@ class Server:
         """Drain queued token records through ONE batched banked scatter
         -- the write-path twin of the tick's batched gather.  The
         artifact's BA/BO circuit places every row in the kernel's index
-        map; no host-side bank arithmetic."""
+        map; no host-side bank arithmetic.
+
+        The writes are padded to the next power of two by repeating the
+        last one, so the artifact builds one record-write executable per
+        power of two and not per prompt length; the kernel writes in
+        order, last write wins, so the repeats leave the table as the
+        real writes do."""
         if not self._pending_records:
             return
         with self._span("serve.scatter"):
             pend, self._pending_records = self._pending_records, []
+            pend += pend[-1:] * ((1 << (len(pend) - 1).bit_length())
+                                 - len(pend))
             rows = np.asarray([p for p, _, _ in pend], np.int64)
             cols = np.asarray([s for _, s, _ in pend], np.int64)
             vals = np.asarray([t for _, _, t in pend], np.int32)
@@ -395,20 +406,22 @@ class Server:
         """Each active slot's decode input, via ONE batched banked gather.
 
         Stacks every active slot's trailing ``W`` record positions into a
-        ``(slots, W)`` index matrix -- a single ``pallas_call`` resolves
-        all of them through the compiled BA/BO circuit.  The last column
-        is the most recent record: the next decode input.
+        ``(max_batch, W)`` index matrix -- a single ``pallas_call``
+        resolves all of them through the compiled BA/BO circuit; rows past
+        the active slots read address 0 and are dropped, so one gather
+        executable serves every batch size.  The last column is the most
+        recent record: the next decode input.
         """
         self._flush_records()     # queued writes land before any read
         slots = sorted(self.active)
         W = self._gather_window
-        rows = np.zeros((len(slots), W), np.int32)
+        rows = np.zeros((self.max_batch, W), np.int32)
         for i, s in enumerate(slots):
             pos = min(int(self.positions[s]), self.max_len)
             rows[i] = np.clip(np.arange(pos - W, pos), 0, self.max_len - 1)
-        got = self._kv_art.gather(self.kv_records, jnp.asarray(rows))
+        got = self._kv_art.gather(self.kv_records, rows)
         with self._span("serve.gather.wait"):
-            got = np.asarray(got)                  # (slots, W, max_batch)
+            got = np.asarray(got)               # (max_batch, W, max_batch)
         layout = self._kv_art.describe()
         out = {}
         for i, s in enumerate(slots):
@@ -640,6 +653,11 @@ class Server:
         if tr.metrics is not None:
             tr.metrics.set_gauge("serve_active_slots", len(self.active))
             tr.metrics.set_gauge("serve_queue_depth", len(self.queue))
+            if self._kv_art is not None:    # of the layout being served
+                for op, n in self._kv_art.kernel_builds.items():
+                    tr.metrics.set_gauge("banked_kernel_builds", n, op=op)
+                for op, n in self._kv_art.kernel_calls.items():
+                    tr.metrics.set_gauge("banked_kernel_calls", n, op=op)
         self._trace_ticks += 1
         if self._trace_ticks >= self._SERVE_TRACE_TICKS:
             self._end_trace()

@@ -185,6 +185,93 @@ def test_ops_scatter_banked_gather_round_trip():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(vals))
 
 
+def test_one_cached_gather_serves_every_index_matrix_of_its_shape():
+    """The jitted gather is built once for an index shape; index
+    matrices of that shape with other values reuse it and still match
+    the numpy backend."""
+    import jax.numpy as jnp
+
+    plan = BankingPlanner().plan(_reader_program(), "table")
+    art, ref_art = plan.compile(), plan.compile(backend="numpy")
+    rng = np.random.default_rng(3)
+    flat = rng.integers(0, 1 << 20, size=(256, 8)).astype(np.int32)
+    table = art.pack(jnp.asarray(flat))
+    for _ in range(4):
+        idx = rng.integers(0, 256, size=(8, 4)).astype(np.int32)
+        np.testing.assert_array_equal(
+            np.asarray(art.gather(table, idx)),
+            ref_art.gather(np.asarray(table), idx))
+    assert art.kernel_builds["gather"] == 1
+    assert art.kernel_calls["gather"] == 4
+    art.gather(table, idx[:, :2])                     # a new shape builds
+    assert art.kernel_builds["gather"] == 2
+
+
+def _tiny_server(art, max_len):
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models import get_model
+    from repro.runtime.server import Server
+
+    cfg = dataclasses.replace(
+        get_arch("qwen2_7b").reduced(), n_layers=1, d_model=32, d_ff=64,
+        vocab=64, n_heads=2, n_kv_heads=2, head_dim=16)
+    return Server(get_model(cfg), max_batch=2, max_len=max_len,
+                  kv_plan=art)
+
+
+def _kv_layouts(max_len):
+    from repro.core import compile_trivial
+    from repro.runtime.server import _page_program
+
+    program = _page_program(max_len, 8, 2)
+    solved = BankingPlanner().plan(program, "kv_pool").compile()
+    assert solved.n_banks > 1
+    return compile_trivial(program.memories["kv_pool"]), solved
+
+
+def test_padded_record_flush_equals_the_unpadded_write():
+    """Server pads a record flush to a power of two by repeating its last
+    write: the table ends up as the unpadded writes leave it, duplicate
+    addresses last-write-wins, through one 8-long executable."""
+    _, solved = _kv_layouts(32)
+    server = _tiny_server(solved, 32)
+    writes = [(0, 0, 5), (3, 1, 7), (3, 1, 9), (31, 0, 11), (0, 0, 13)]
+    server._pending_records = list(writes)
+    server._flush_records()
+    want = np.zeros((32, 2), np.int32)
+    for pos, slot, tok in writes:
+        want[pos, slot] = tok
+    np.testing.assert_array_equal(
+        np.asarray(solved.unpack(server.kv_records)), want)
+    assert solved.kernel_builds["scatter_elems"] == 1
+    # key: op, interpret, then (shape, dtype) of table, rows, cols, values
+    assert [k[3][0] for k in solved._kernels
+            if k[0] == "scatter_elems"] == [(8,)]
+
+
+def test_a_swapped_layout_gathers_through_its_own_executable():
+    """After ``Server._swap_to`` the gather reads the same logical
+    records through the new artifact's executable, built once; the old
+    artifact's counts stay as they were."""
+    trivial, solved = _kv_layouts(32)
+    server = _tiny_server(trivial, 32)
+    server._pending_records = [(p, p % 2, 100 + p) for p in range(32)]
+    server._flush_records()
+    rows = np.arange(8, dtype=np.int32).reshape(2, 4) * 3
+    before = np.asarray(server._kv_art.gather(server.kv_records, rows))
+    old = dict(trivial.kernel_builds), dict(trivial.kernel_calls)
+    server._swap_to(solved)
+    assert server._kv_art is solved
+    for _ in range(3):
+        after = np.asarray(server._kv_art.gather(server.kv_records, rows))
+        np.testing.assert_array_equal(after, before)
+    assert solved.kernel_builds["gather"] == 1
+    assert solved.kernel_calls["gather"] == 3
+    assert (dict(trivial.kernel_builds), dict(trivial.kernel_calls)) == old
+
+
 def test_trivial_fallback_artifact_is_single_bank_rowmajor():
     from repro.core import compile_trivial
 

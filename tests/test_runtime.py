@@ -222,6 +222,44 @@ def test_a_tick_s_compiles_land_on_the_banked_call_that_ran_them(
             + s.attrs["compile_s"])
 
 
+def test_later_ticks_reuse_the_banked_kernels_built_by_the_first():
+    """Each banked kernel is built once per layout and shape: after the
+    first decode tick at a steady batch, ticks compile nothing in the
+    gather or the record write, the served artifact's build counts stay
+    put while its call counts grow, and every gathered token still
+    equals the token the host recorded."""
+    from repro.core.service import PlanService
+    svc = PlanService(workers=1)
+    try:
+        server = _tiny_server(svc)
+        server.tick()
+        tr, tid = server._tracer()
+        metrics = tr.metrics
+        builds0 = {op: metrics.gauge("banked_kernel_builds", op=op)
+                   for op in ("gather", "scatter_elems")}
+        calls0 = {op: metrics.gauge("banked_kernel_calls", op=op)
+                  for op in ("gather", "scatter_elems")}
+        assert builds0["gather"] == 1 and builds0["scatter_elems"] >= 1
+        n_first = len(tr.spans(tid))
+        for _ in range(3):
+            server.tick()
+        later = tr.spans(tid)[n_first:]
+    finally:
+        svc.shutdown()
+    assert sum(s.name == "serve.tick" for s in later) == 3
+    banked = [s for s in later if s.name in ("serve.gather",
+                                             "serve.scatter")]
+    assert len(banked) >= 6
+    for s in banked:
+        assert s.attrs.get("compiles", 0) == 0, (s.name, s.attrs)
+    for op in ("gather", "scatter_elems"):
+        assert metrics.gauge("banked_kernel_builds", op=op) == builds0[op]
+        assert metrics.gauge("banked_kernel_calls", op=op) == \
+            calls0[op] + 3
+    assert server.record_mismatches == 0
+    assert sum(server.record_checks.values()) == 2 * 4
+
+
 def test_serve_spans_are_on_the_profiler_host_plane(first_tick, tmp_path):
     server, _ = first_tick
     with jax.profiler.trace(str(tmp_path)):
@@ -259,7 +297,9 @@ def test_serve_metrics_reach_the_service_endpoint_without_tick_telemetry():
         svc.shutdown()
     for name in ("serve_tick_ms_count", "serve_gather_ms_count",
                  "serve_scatter_ms_count", "serve_queue_wait_ms_count",
-                 "serve_active_slots", "serve_queue_depth", "compiles"):
+                 "serve_active_slots", "serve_queue_depth", "compiles",
+                 'banked_kernel_builds{op="gather"}',
+                 'banked_kernel_calls{op="scatter_elems"}'):
         assert name in body, name
     ops = {r.op for r in hub.log.records()}
     assert "gather" in ops and "tick" not in ops

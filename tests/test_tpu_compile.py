@@ -12,6 +12,7 @@ this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +84,28 @@ def test_banked_kernels_compile_for_v5e(one_chip, kv_artifacts, layout):
                     one_chip, table, vec, ((slots, slots), jnp.int32))
     for compiled in (gather, record, rows):
         assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("layout", ["trivial", "solved"])
+def test_cached_banked_executables_compile_for_v5e(one_chip, kv_artifacts,
+                                                   layout):
+    """The executables the artifact builds once and keeps -- the gather
+    and the per-slot record write, each with the record table's padding
+    to whole tiles inside -- compile through Mosaic as they are, and the
+    Mosaic call keeps the ``tpu_custom_call`` name a device trace shows
+    for the banked kernels."""
+    art = kv_artifacts[layout]
+    slots = qwen2_7b.STAGE_SLOTS
+    table = jax.ShapeDtypeStruct(art.layout.table_shape(slots), jnp.int32,
+                                 sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((slots, GATHER_WINDOW), jnp.int32,
+                                sharding=one_chip)
+    for op, args in (("gather", (table, rows)),
+                     ("scatter_elems", (table, vec, vec, vec))):
+        text = art._kernel(op, False, *args).lower(*args).compile().as_text()
+        assert re.search(r"%tpu_custom_call\S* = \S+ custom-call\(", text), op
+        assert " pad(" in text, op          # 8 lanes -> one (1, 128) tile
 
 
 @pytest.mark.parametrize("width,dtype", [(128, jnp.float32),
