@@ -5,7 +5,8 @@ checks it, for a described TPU v5e, without a chip.
 
 For every configuration in ``BENCHMARK.json``: the program's
 ``serve_step`` at the served batch and cache, as ``Server`` jits it, and
-the benchmark's float32 reference over a whole cache of positions.  It
+the float32 reference the configuration names over a whole cache of
+positions, with the weights its leaf tree defines.  It
 prints each program's ``memory_analysis``; what the chip's compiler
 would refuse (a program that does not fit, a kernel Mosaic rejects) is
 refused here.
@@ -30,11 +31,10 @@ def main() -> None:
 
     from repro.launch.steps import make_serve_step
     from repro.models import get_model
-    from servebench import reference
     from servebench.harness import arch_config
     from servebench.modelspec import load_spec
-    from servebench.spec import load_benchmark
-    from servebench.weights import leaves
+    from servebench.spec import load_benchmark, load_reference
+    from servebench.weights import model_leaves
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
@@ -66,11 +66,13 @@ def main() -> None:
         report("serve_step", jax.jit(make_serve_step(model)).lower(
             params, cache, tokens).compile())
 
+        reference = load_reference(spec.raw, ROOT)
+        tree = model_leaves(spec)
         ours = placed({k: jax.ShapeDtypeStruct(v[0], jnp.dtype(v[1]))
-                       for k, v in leaves(spec)["top"].items()}
+                       for k, v in tree["top"].items()}
                       | {"layers": {k: jax.ShapeDtypeStruct(
                           v[0], jnp.dtype(v[1])) for k, v in
-                          leaves(spec)["layers"].items()}})
+                          tree["layers"].items()}})
         rows = jax.ShapeDtypeStruct((spec.slots, spec.max_len), jnp.int32,
                                     sharding=chip)
         for quant in (False, True):

@@ -10,40 +10,61 @@ kernels are counted by the logical rows and elements they read and
 write, not by the tile padding.  So a count never depends on how the
 program implements a step, and a share of the roofline never exceeds
 100%.
+
+The weights are counted from the configuration's leaf tree
+(``weights.model_leaves``), so a leaf that a configuration's reference
+adds is counted with the others: every layer leaf is read once a step,
+except the expert stacks (``we_*``, shaped layers x experts x ...), of
+which a token multiplies by ``top_k`` experts and a step reads the experts
+its tokens are routed to; of the top-level leaves, the embedding is a
+lookup of the rows fed and every other leaf (the head, the final norm) is
+read once.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple
 
 from .modelspec import ModelSpec
+from .weights import model_leaves
 
 BF16 = 2
-F32 = 4
 INT32 = 4
+EXPERT_PREFIX = "we_"
+EMBED = "embed"
 
 
-def _attn_weights(s: ModelSpec) -> int:
-    q, kv = s.heads * s.head_dim, s.kv_heads * s.head_dim
-    n = s.d_model * (q + 2 * kv) + q * s.d_model
-    if s.qkv_bias:
-        n += q + 2 * kv
-    return n + 2 * s.d_model          # two norm gains
+def _weights(s: ModelSpec) -> Tuple[int, int, int, int, int]:
+    """Elements and bytes read once a step (every leaf but the embedding
+    and the expert stacks), elements and bytes of one expert in one
+    layer, and the bytes of one embedding row."""
+    import jax.numpy as jnp
 
-
-def _expert_weights(s: ModelSpec) -> int:
-    return 3 * s.d_model * s.expert_ff
+    tree = model_leaves(s)
+    once = once_b = expert = expert_b = 0
+    for name, (shape, dt, _) in tree["layers"].items():
+        size = jnp.dtype(dt).itemsize
+        if name.startswith(EXPERT_PREFIX):
+            n = math.prod(shape[2:])
+            expert, expert_b = expert + n, expert_b + n * size
+        else:
+            n = math.prod(shape)
+            once, once_b = once + n, once_b + n * size
+    for name, (shape, dt, _) in tree["top"].items():
+        if name != EMBED:
+            n = math.prod(shape)
+            once, once_b = once + n, once_b + n * jnp.dtype(dt).itemsize
+    shape, dt, _ = tree["top"][EMBED]
+    return once, once_b, expert, expert_b, \
+        math.prod(shape[1:]) * jnp.dtype(dt).itemsize
 
 
 def active_params(s: ModelSpec) -> int:
     """Parameters one token multiplies by (the embedding is a lookup and
     does not count; the output head does)."""
-    per_layer = _attn_weights(s)
-    if s.moe:
-        per_layer += s.d_model * s.experts + s.top_k * _expert_weights(s)
-    else:
-        per_layer += 3 * s.d_model * s.d_ff
-    return s.layers * per_layer + s.vocab * s.d_model + s.d_model
+    once, _, expert, _, _ = _weights(s)
+    return once + s.layers * s.top_k * expert
 
 
 def step_cost(s: ModelSpec, lengths: Sequence[int],
@@ -56,20 +77,16 @@ def step_cost(s: ModelSpec, lengths: Sequence[int],
     n = len(lengths)
     if n == 0:
         return 0.0, 0.0
+    _, once_b, expert, expert_b, embed_row_b = _weights(s)
     kv_row = 2 * s.kv_heads * s.head_dim                  # k and v
     ctx = sum(lengths)
     flops = 2.0 * active_params(s) * n
     flops += s.layers * 2 * 2 * s.heads * s.head_dim * ctx   # qk and pv
-    weights = s.layers * _attn_weights(s) * BF16
-    if s.moe:
+    weights = once_b + n * embed_row_b
+    if expert:
         if experts_used is None or len(experts_used) != s.layers:
             raise ValueError("a MoE step needs the experts used per layer")
-        weights += s.layers * s.d_model * s.experts * F32    # router
-        weights += sum(experts_used) * _expert_weights(s) * BF16
-    else:
-        weights += s.layers * 3 * s.d_model * s.d_ff * BF16
-    weights += (s.vocab * s.d_model + s.d_model) * BF16      # head, ln_f
-    weights += n * s.d_model * BF16                          # embed rows
+        weights += sum(experts_used) * expert_b
     cache = s.layers * kv_row * BF16 * (ctx - n)             # read
     cache += s.layers * kv_row * BF16 * n                    # new rows
     return flops, float(weights + cache)

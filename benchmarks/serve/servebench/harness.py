@@ -126,10 +126,22 @@ def tracked_class(Request):
 
 def arch_config(spec: ModelSpec):
     """The program's configuration type, filled from a configuration
-    file."""
+    file, with the file's ``"program"`` options applied: each key a field
+    of ``ArchConfig``, each value what the program is handed.  A key the
+    program does not have is an error, so a model that needs an option
+    the program lacks fails before it serves another model."""
+    import dataclasses
+
     from repro.configs.base import ArchConfig
 
-    return ArchConfig(
+    options = dict(spec.raw.get("program", {}))
+    unknown = sorted(set(options) - {f.name for f in
+                                     dataclasses.fields(ArchConfig)})
+    if unknown:
+        raise ValueError(f"configuration {spec.name!r} asks the program "
+                         f"for options it does not have: {unknown} are "
+                         f"not fields of ArchConfig")
+    return dataclasses.replace(ArchConfig(
         name=spec.name, family="moe" if spec.moe else "dense",
         n_layers=spec.layers, d_model=spec.d_model, n_heads=spec.heads,
         n_kv_heads=spec.kv_heads,
@@ -137,7 +149,7 @@ def arch_config(spec: ModelSpec):
         head_dim=spec.head_dim, qkv_bias=spec.qkv_bias,
         rope_theta=spec.rope_theta, tie_embeddings=False,
         norm_eps=spec.norm_eps, n_experts=spec.experts, top_k=spec.top_k,
-        moe_d_ff=spec.expert_ff, shared_expert=False)
+        moe_d_ff=spec.expert_ff, shared_expert=False), **options)
 
 
 @dataclass

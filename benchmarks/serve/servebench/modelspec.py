@@ -3,16 +3,21 @@
 The file keeps the published ``config.json`` keys at its top level, as
 run (a cut key holds the value run, and is named in ``reduced``), plus
 ``assumed`` (sizes the published file does not state), ``serve`` (slots,
-cache length and page of the served deployment) and ``limits`` (what the
-correctness comparison allows).  The benchmark's reference, weights and
-operation counts read this object; only the harness turns it into the
-program's own configuration type.
+cache length and page of the served deployment), ``limits`` (what the
+correctness comparison allows), ``reference`` (the file of its plain
+reference, whose ``compare`` decides ``correct`` and whose ``leaves``, where
+it defines one, gives the parameter tree) and, where the program needs
+options to run this model, ``program`` (fields of the program's
+``ArchConfig`` and their values).  The benchmark's reference, weights and
+operation counts read this object, and a reference reads keys it alone
+knows from ``raw``; only the harness turns it into the program's own
+configuration type.
 """
 
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict
 
@@ -38,6 +43,8 @@ class ModelSpec:
     max_len: int
     page: int
     limits: Dict[str, float]
+    raw: Dict[str, Any] = field(default_factory=dict, compare=False,
+                                repr=False)     # the file as read
 
     @property
     def moe(self) -> bool:
@@ -76,5 +83,5 @@ def spec_from_dict(c: Dict[str, Any]) -> ModelSpec:
         expert_ff=int(key("intermediate_size")) if experts else 0,
         norm_topk=bool(c.get("norm_topk_prob", False)),
         slots=int(serve["slots"]), max_len=int(serve["max_len"]),
-        page=int(serve["page"]), limits=dict(c.get("limits", {})),
+        page=int(serve["page"]), limits=dict(c.get("limits", {})), raw=c,
     )

@@ -22,6 +22,18 @@ The control is the same computation with every linear layer's weights
 and inputs rounded to float8 (e4m3, scaled by their absolute maximum per
 output column and per row): the step below bfloat16 that a later change
 might be tempted to take.  Attention and the norms stay in float32.
+
+For a mixture of experts the reference also reports, at each served
+token's position, how near its routes are to a tie: the smallest margin,
+over layers, between the k-th and the (k+1)-th router probability, and
+the largest change of any router probability when the router's input is
+rounded to bfloat16 (what a bf16 program's own rounding can move).  A
+bf16 program may take the other side of a near-tied route, and its token
+is then judged against another mixture than the reference's.
+
+A configuration names this file, or a copy of it, under ``"reference"``;
+a copy may define ``leaves(spec)`` (see ``weights.leaves``) and read keys
+of its own from ``spec.raw``.
 """
 
 from __future__ import annotations
@@ -112,6 +124,7 @@ def _experts(s: ModelSpec, h, lp, quant):
     x = h.reshape(T, D)
     logits = _mm(x, lp["router"], quant)
     probs = jax.nn.softmax(logits, axis=-1)
+    margin, shift = _route_ties(s, x, lp["router"], probs)
     top_p, top_i = jax.lax.top_k(probs, s.top_k)
     if s.norm_topk:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
@@ -129,12 +142,30 @@ def _experts(s: ModelSpec, h, lp, quant):
     nb = T // T_BLOCK
     out = jax.lax.map(block, (x.reshape(nb, T_BLOCK, D),
                               gates.reshape(nb, T_BLOCK, s.experts)))
-    return out.reshape(B, P, D), top_i
+    return out.reshape(B, P, D), (top_i, margin, shift)
+
+
+def _route_ties(s: ModelSpec, x, router, probs):
+    """Per token (T,): the k-th less the (k+1)-th router probability, and
+    the largest change of a router probability when the router's input
+    ``x`` is rounded to bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    if s.top_k < s.experts:
+        near = jax.lax.top_k(probs, s.top_k + 1)[0]
+        margin = near[:, -2] - near[:, -1]
+    else:
+        margin = jnp.full(probs.shape[:1], jnp.inf, jnp.float32)
+    rounded = jax.nn.softmax(_mm(x.astype(jnp.bfloat16), router, False), -1)
+    return margin, jnp.max(jnp.abs(rounded - probs), axis=-1)
 
 
 def _hidden(s: ModelSpec, params, rows, quant: bool):
     """Final-normed hidden states (B, P, D) and, for a mixture of
-    experts, the experts each token is routed to (L, B*P, k)."""
+    experts, the experts each token is routed to (L, B*P, k), with the
+    route margins and bfloat16 shifts of ``_route_ties`` (L, B*P); a
+    dense model's are empty."""
     import jax
     import jax.numpy as jnp
 
@@ -157,13 +188,14 @@ def _hidden(s: ModelSpec, params, rows, quant: bool):
         x = x + _mm(_attention(q, k, v), lp["wo"], quant)
         h = _rms(x, lp["ln2"], s.norm_eps)
         if s.moe:
-            delta, top_i = _experts(s, h, lp, quant)
+            delta, route = _experts(s, h, lp, quant)
         else:
             a = jax.nn.silu(_mm(h, lp["w_gate"], quant)) \
                 * _mm(h, lp["w_up"], quant)
             delta = _mm(a, lp["w_down"], quant)
-            top_i = jnp.zeros((B * P, 0), jnp.int32)
-        return x + delta, top_i
+            none = jnp.zeros((B * P, 0), jnp.float32)
+            route = (jnp.zeros((B * P, 0), jnp.int32), none, none)
+        return x + delta, route
 
     x, routing = jax.lax.scan(body, x, params["layers"])
     return _rms(x, params["ln_f"], s.norm_eps), routing
@@ -193,7 +225,11 @@ def compare(s: ModelSpec, params, rows: np.ndarray, calls: np.ndarray,
     token ``tokens[i]``): how far its reference logit lies below the
     reference's best (``gap``).  With ``control``, also the gap of the
     token the float8 control puts first (``control_gap``).  ``routing``
-    is the reference's expert choice per layer, position and row."""
+    is the reference's expert choice per layer, position and row.  For a
+    mixture of experts, per served token, ``margin`` is the smallest
+    route margin over layers at its position and ``route_shift`` the
+    largest bfloat16 shift (``_route_ties``); both are None for a dense
+    model."""
     import jax
     import jax.numpy as jnp
 
@@ -210,11 +246,15 @@ def compare(s: ModelSpec, params, rows: np.ndarray, calls: np.ndarray,
 
     @jax.jit
     def ref_pass(params, rows, sel, tok):
-        h, routing = _hidden(s, params, rows, False)
+        h, (routing, margin, shift) = _hidden(s, params, rows, False)
         logits = _head(params, h[sel[:, 0], sel[:, 1]], False)
         best = jnp.max(logits, axis=-1)
         got = jnp.take_along_axis(logits, tok[:, None], axis=-1)[:, 0]
-        return best - got, best, logits, routing
+        if s.moe:
+            at = (slice(None), sel[:, 0], sel[:, 1])
+            margin = jnp.min(margin.reshape(-1, B, Pp)[at], axis=0)
+            shift = jnp.max(shift.reshape(-1, B, Pp)[at], axis=0)
+        return best - got, best, logits, routing, margin, shift
 
     @jax.jit
     def ctrl_pass(params, rows, sel):
@@ -223,12 +263,15 @@ def compare(s: ModelSpec, params, rows: np.ndarray, calls: np.ndarray,
                           axis=-1)
 
     with jax.default_matmul_precision("highest"):
-        gap, best, logits, routing = ref_pass(params, jnp.asarray(padded),
-                                              jnp.asarray(sel),
-                                              jnp.asarray(tok))
+        gap, best, logits, routing, margin, shift = ref_pass(
+            params, jnp.asarray(padded), jnp.asarray(sel), jnp.asarray(tok))
         out = {"gap": np.asarray(gap)[:n], "control_gap": None,
-               "routing": (np.asarray(routing).reshape(
-                   s.layers, B, Pp, -1)[:, :, :P] if s.moe else None)}
+               "routing": None, "margin": None, "route_shift": None}
+        if s.moe:
+            out.update(routing=np.asarray(routing).reshape(
+                s.layers, B, Pp, -1)[:, :, :P],
+                margin=np.asarray(margin)[:n],
+                route_shift=np.asarray(shift)[:n])
         if control:
             ctok = ctrl_pass(params, jnp.asarray(padded), jnp.asarray(sel))
             cgap = best - jnp.take_along_axis(logits, ctok[:, None],

@@ -11,22 +11,33 @@ import numpy as np
 
 from . import harness, timeline, trace as tr
 from .modelspec import ModelSpec
-from .reference import compare
-from .spec import Cell, load_reader
+from .spec import Cell, load_reader, load_reference
 from .traffic import generate
 from .weights import check_tree, make_weights
 from .work import window_span
 
 
-GAP_NUMBERS = ("logit_gap", "logit_gap_mean", "argmax_miss_share")
+GAP_NUMBERS = ("logit_gap", "logit_gap_mean", "argmax_miss_share",
+               "near_tie_share")
 
 
-def gap_numbers(gaps: np.ndarray) -> Dict[str, float]:
+def gap_numbers(gaps: np.ndarray, margin: Optional[np.ndarray] = None,
+                route_tie: float = 0.0) -> Dict[str, Optional[float]]:
     """The widest gap, the mean gap, and the share of tokens that are not
-    the reference's first choice."""
-    return {"logit_gap": float(np.max(gaps)),
-            "logit_gap_mean": float(np.mean(gaps)),
-            "argmax_miss_share": float(np.mean(gaps > 0))}
+    the reference's first choice.  Given the route margin of each token
+    (a mixture of experts), a token whose margin is under ``route_tie``
+    is near-tied: the widest gap leaves it out, and ``near_tie_share``
+    is the share left out; the mean and the share missed cover every
+    token."""
+    out = {"logit_gap": float(np.max(gaps)),
+           "logit_gap_mean": float(np.mean(gaps)),
+           "argmax_miss_share": float(np.mean(gaps > 0))}
+    if margin is not None:
+        tied = margin < route_tie
+        out["logit_gap"] = float(np.max(gaps[~tied])) if not tied.all() \
+            else None
+        out["near_tie_share"] = float(np.mean(tied))
+    return out
 
 
 def reduce_trace(run: harness.Run, trace_dir: str) -> Dict[str, Any]:
@@ -66,6 +77,7 @@ def run_cell(cell: Cell, spec: ModelSpec, mix: Dict[str, Any], seed: int,
 
     info: List[str] = []
     run = harness.Run(spec, mix, seconds, t_start=t_start, peaks=peaks)
+    reference = load_reference(spec.raw)
     params = make_weights(spec, seed)
     check_tree(harness.expected_tree(spec), params)
     jax.block_until_ready(params)
@@ -90,16 +102,26 @@ def run_cell(cell: Cell, spec: ModelSpec, mix: Dict[str, Any], seed: int,
     s = harness.served(run)
     positions = run.rows.shape[1]
     checks: Dict[str, Dict[str, float]] = {}
-    found: Dict[str, Optional[float]] = {k: None for k in GAP_NUMBERS}
+    # near_tie_share exists only where the reference gives route margins
+    found: Dict[str, Optional[float]] = dict.fromkeys(GAP_NUMBERS[:3])
     judged = found
     if positions <= spec.max_len and len(s.tokens):
-        out = compare(spec, params, run.rows, s.calls, s.slots, s.tokens,
-                      control=control)
+        out = reference.compare(spec, params, run.rows, s.calls, s.slots,
+                                s.tokens, control=control)
         run.routing = out["routing"]
-        judged = found = gap_numbers(out["gap"])
+        margin = out.get("margin")
+        tie = float(spec.limits.get("route_tie", 0.0))
+        judged = found = gap_numbers(out["gap"], margin, tie)
+        if margin is not None:
+            info.append(
+                f"routes: smallest margin {float(np.min(margin))!r}, "
+                f"largest bfloat16 shift "
+                f"{float(np.max(out['route_shift']))!r}; "
+                f"{int(np.sum(margin < tie))} of {len(margin)} tokens "
+                f"near-tied (margin under route_tie {tie!r})")
         if control:
             # the control takes the program's place in the comparison
-            judged = gap_numbers(out["control_gap"])
+            judged = gap_numbers(out["control_gap"], margin, tie)
             info.append("control (float8 linear layers) against the "
                         "reference, in the program's place: " + ", ".join(
                             f"{k} {v!r}" for k, v in judged.items()))
@@ -108,7 +130,7 @@ def run_cell(cell: Cell, spec: ModelSpec, mix: Dict[str, Any], seed: int,
         f"{k} {v!r}" for k, v in found.items()))
     for name in GAP_NUMBERS:
         if name in spec.limits:
-            checks[name] = {"value": judged[name],
+            checks[name] = {"value": judged.get(name),
                             "limit": float(spec.limits[name])}
     checks["record_mismatches"] = {"value": s.record_mismatches, "limit": 0}
     checks["feed_mismatches"] = {"value": s.feed_mismatches, "limit": 0}

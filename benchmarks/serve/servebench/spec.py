@@ -3,15 +3,20 @@ each found by its name in a file of its own."""
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import re
+import sys
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List
 
-HERE = Path(__file__).resolve().parent.parent        # benchmarks/serve
+PACKAGE = Path(__file__).resolve().parent            # servebench
+HERE = PACKAGE.parent                                # benchmarks/serve
 ROOT = HERE.parent.parent                            # the checkout
+REFERENCE_KEY = "reference"
 
 
 @dataclass
@@ -67,3 +72,37 @@ def load_reader(metric: str) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def reference_path(config: Dict[str, Any], root: Path = ROOT) -> Path:
+    """The file a configuration's ``"reference"`` key names, relative to
+    the checkout's root."""
+    name = config.get("name")
+    if REFERENCE_KEY not in config:
+        raise KeyError(f"configuration {name!r} has no {REFERENCE_KEY!r} "
+                       f"key: it names the file of its plain reference")
+    path = Path(config[REFERENCE_KEY])
+    path = path if path.is_absolute() else root / path
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {name!r}: its {REFERENCE_KEY!r} key names "
+            f"{config[REFERENCE_KEY]!r}, and there is no file at {path}")
+    return path.resolve()
+
+
+def load_reference(config: Dict[str, Any], root: Path = ROOT) -> ModuleType:
+    """The reference module a configuration names: its ``compare`` decides
+    ``correct``, and its ``leaves(spec)``, where it defines one, the
+    parameter tree.  A file outside this package is loaded as a module of
+    it, so that its relative imports (``from .modelspec import ...``)
+    reach the benchmark's own modules."""
+    path = reference_path(config, root)
+    if path.parent == PACKAGE:
+        return importlib.import_module(f"{__package__}.{path.stem}")
+    name = f"{__package__}._reference_" + re.sub(r"\W", "_", str(path))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[name]

@@ -3,7 +3,9 @@ they are served in.
 
 The tree uses the serving program's leaf names and layer stacking (the
 layers stacked on a leading axis), so it can be handed to the program as
-its parameters; the reference reads the same tree.  Norm gains are
+its parameters; the reference reads the same tree.  A configuration's
+reference may define the tree itself, as ``leaves(spec)`` in the form of
+:func:`leaves`; the weights and the operation counts then follow it.  Norm gains are
 stored as ``gain - 1`` (the program's convention: a zero leaf is a unit
 gain).
 """
@@ -14,6 +16,7 @@ import math
 from typing import Dict, Tuple
 
 from .modelspec import ModelSpec
+from .spec import load_reference
 
 # leaf name -> (shape, dtype name, standard deviation)
 Leaves = Dict[str, Tuple[Tuple[int, ...], str, float]]
@@ -59,6 +62,12 @@ def leaves(s: ModelSpec) -> Dict[str, Leaves]:
     return {"top": top, "layers": layer}
 
 
+def model_leaves(s: ModelSpec) -> Dict[str, Leaves]:
+    """The tree of ``s``: its reference's ``leaves(spec)`` where the
+    reference defines one, else :func:`leaves`."""
+    return getattr(load_reference(s.raw), "leaves", leaves)(s)
+
+
 def seed_key(seed: int):
     """A PRNG key from any non-negative whole number; bits above 32 are
     folded in rather than dropped."""
@@ -73,7 +82,7 @@ def make_weights(s: ModelSpec, seed: int):
     import jax
     import jax.numpy as jnp
 
-    spec = leaves(s)
+    spec = model_leaves(s)
     names = sorted(spec["top"]) + [f"layers/{n}" for n in
                                    sorted(spec["layers"])]
 
@@ -98,17 +107,22 @@ def make_weights(s: ModelSpec, seed: int):
     return gen(seed_key(seed))
 
 
-def check_tree(params_shapes, expected) -> None:
+def check_tree(program, benchmark) -> None:
     """Raise unless two trees of shapes agree leaf by leaf (name, shape,
     dtype): the program's parameter layout has to be the one the
-    benchmark makes."""
+    benchmark makes.  The message names each leaf that differs."""
     import jax
 
-    a = {jax.tree_util.keystr(p): (tuple(x.shape), str(x.dtype)) for p, x
-         in jax.tree_util.tree_flatten_with_path(params_shapes)[0]}
-    b = {jax.tree_util.keystr(p): (tuple(x.shape), str(x.dtype)) for p, x
-         in jax.tree_util.tree_flatten_with_path(expected)[0]}
+    def flat(tree):
+        return {jax.tree_util.keystr(p): (tuple(x.shape), str(x.dtype))
+                for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+    a, b = flat(program), flat(benchmark)
     if a != b:
-        diff = sorted(set(a.items()) ^ set(b.items()))
-        raise ValueError(f"the program's parameter tree differs from the "
-                         f"benchmark's: {diff[:8]}")
+        differ = [f"{k}: program {a[k]}, benchmark {b[k]}"
+                  for k in sorted(set(a) & set(b)) if a[k] != b[k]]
+        raise ValueError(
+            f"the program's parameter tree differs from the benchmark's: "
+            f"leaves the program lacks {sorted(set(b) - set(a))}, leaves "
+            f"the benchmark lacks {sorted(set(a) - set(b))}, leaves that "
+            f"differ {differ[:8]}")

@@ -209,7 +209,46 @@ MOE_WIDE = {"name": "moe-wide", "hidden_size": 2048, "intermediate_size": 1024,
             "num_experts_per_tok": 8, "norm_topk_prob": True,
             "vocab_size": 50304, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
             "qkv_bias": False,
-            "serve": {"slots": 8, "max_len": 4096, "page": 128}}
+            "serve": {"slots": 8, "max_len": 4096, "page": 128},
+            "reference": "benchmarks/serve/servebench/reference.py"}
+
+
+# a leaf the program does not have, added by a reference copy's `leaves`
+EXTRA_LEAF = """
+from .weights import leaves as _base_leaves
+
+
+def leaves(s):
+    tree = _base_leaves(s)
+    tree["layers"]["q_norm"] = ((s.layers, s.d_model), "bfloat16", 0.1)
+    return tree
+"""
+
+# a reference copy that notes each comparison it makes and defines the
+# benchmark's own tree
+NOTED = """
+from .weights import leaves as _base_leaves
+
+NOTED = []
+_compare = compare
+
+
+def compare(*args, **kw):
+    NOTED.append(len(args[-1]))
+    return _compare(*args, **kw)
+
+
+def leaves(s):
+    return _base_leaves(s)
+"""
+
+
+def _reference_copy(tmp_path, name, extra):
+    """A copy of the benchmark's reference outside its package, with
+    ``extra`` appended."""
+    path = tmp_path / f"{name}_reference.py"
+    path.write_text((specmod.PACKAGE / "reference.py").read_text() + extra)
+    return path
 
 
 def test_counts_grow_with_context_and_stay_under_the_weights_for_moe():
@@ -233,6 +272,30 @@ def test_counts_grow_with_context_and_stay_under_the_weights_for_moe():
         peaks_for("TPU v9 giant")
 
 
+def test_counts_follow_the_leaves_and_keep_the_formulas_numbers():
+    # the counts of the formulas that counted each kind of layer by hand,
+    # before the counts followed the leaf tree
+    dense = load_spec(specmod.ROOT / "benchmarks/serve/configs/"
+                      "qwen2-7b-14L.json")
+    assert active_params(dense) == 3_807_810_048
+    assert step_cost(dense, [100] * 8) == (61_085_523_968, 7_638_615_040)
+    moe = spec_from_dict(MOE_WIDE)
+    assert active_params(moe) == 640_976_896
+    assert step_cost(moe, [100] * 8, [42] * 8) == (10_308_059_136,
+                                                   4_759_064_576)
+
+
+def test_a_leaf_that_a_reference_adds_is_counted(tmp_path):
+    moe = spec_from_dict(MOE_WIDE)
+    more = spec_from_dict(dict(MOE_WIDE, reference=str(_reference_copy(
+        tmp_path, "qk", EXTRA_LEAF))))
+    L, D = moe.layers, moe.d_model
+    assert active_params(more) - active_params(moe) == L * D
+    f0, b0 = step_cost(moe, [100] * 8, [42] * 8)
+    f1, b1 = step_cost(more, [100] * 8, [42] * 8)
+    assert (f1 - f0, b1 - b0) == (2 * L * D * 8, 2 * L * D)
+
+
 # -- the comparison: control and planted faults, at a test size -------------------
 
 TINY = {"name": "tiny", "hidden_size": 64, "num_attention_heads": 4,
@@ -240,6 +303,7 @@ TINY = {"name": "tiny", "hidden_size": 64, "num_attention_heads": 4,
         "num_hidden_layers": 2, "vocab_size": 1024, "rope_theta": 10000.0,
         "rms_norm_eps": 1e-6, "qkv_bias": True,
         "serve": {"slots": 4, "max_len": 512, "page": 16},
+        "reference": "benchmarks/serve/servebench/reference.py",
         # limits for this size, between the program's readings on CPU
         # (widest gap about 0.01, mean about 1e-4) and the float8
         # control's (widest 0.17, mean 3.5e-3)
@@ -271,11 +335,19 @@ def _run(config, fault=None, control=False, seconds=1.0, seed=2**31 + 5):
 
 
 def _moe():
-    # an MoE configuration compares the mean gap only (program mean
-    # about 1e-4, control 1.2e-2 on CPU)
+    # An MoE configuration.  Its widest gap leaves out near-tied tokens:
+    # route_tie is twice the largest change of a router probability that
+    # rounding the router's input to bfloat16 makes (0.00217 over 28 CPU
+    # runs of the 8-s window, 276 served tokens each).  Over those runs
+    # the program's widest gap read at most 0.0286, the float8 control's
+    # at least 0.176, and at most 9.4% of the tokens were near-tied (mean
+    # 6.2%).  The mean gap is not compared here: one token on a flipped
+    # route (margin 1e-5 to 0.0019) lifts the program's to 0.0044, and
+    # the control's reads 0.0076 at least, under three times that.
     return dict(TINY, intermediate_size=32, qkv_bias=False, num_experts=4,
                 num_experts_per_tok=2, norm_topk_prob=True,
-                limits={"logit_gap_mean": 0.001})
+                limits={"logit_gap": 0.08, "near_tie_share": 0.15,
+                        "route_tie": 0.0044})
 
 
 def _numbers(line):
@@ -290,19 +362,124 @@ def test_program_is_correct_and_the_float8_control_is_not(config, control):
     result, info = _run(config, control=control, seconds=8.0)
     checks = result["checks"]
     assert list(result)[-1] == "checks"
-    assert set(config["limits"]) <= set(checks)
+    assert set(config["limits"]) - {"route_tie"} <= set(checks)
     program = _numbers(next(x for x in info if x.startswith("program")))
-    assert all(program[k] <= lim for k, lim in config["limits"].items()), \
+    compared = {k: lim for k, lim in config["limits"].items()
+                if k != "route_tie"}
+    assert all(program[k] <= lim for k, lim in compared.items()), \
         (program, config["limits"])
     if control:
         # the control in the program's place fails a compared number
         assert result["correct"] is False, checks
         assert any(checks[k]["value"] > lim
-                   for k, lim in config["limits"].items()), checks
+                   for k, lim in compared.items()), checks
     else:
         assert result["correct"] is True, checks
     assert checks["positions"]["value"] <= config["serve"]["max_len"]
     assert set(result["metrics"]) >= {"itl_p50_ms", "setup_s"}
+
+
+# gaps the reference gave TINY's seeded weights on the rows of
+# `_fixed_rows`, before a configuration named its own reference
+PARENT_GAP = [1.7149102687835693, 2.816540241241455, 2.3991665840148926,
+              2.1973037719726562, 2.5881218910217285, 1.4710813760757446,
+              3.1419312953948975, 3.253419876098633, 2.813732385635376,
+              3.3828494548797607]
+PARENT_CONTROL_GAP = [0.0, 0.0, 0.032691001892089844, 0.0, 0.0, 0.0, 0.0,
+                      0.09197092056274414, 0.0, 0.0]
+
+
+def _fixed_rows():
+    rng = np.random.default_rng(11)
+    rows = rng.integers(0, 1024, (4, 40)).astype(np.int32)
+    calls = np.array([0, 3, 9, 17, 25, 33, 39, 39, 12, 30])
+    slots = np.array([0, 1, 2, 3, 0, 1, 2, 3, 3, 2])
+    return rows, calls, slots, rng.integers(0, 1024, len(calls))
+
+
+def test_a_dense_comparison_gives_the_gaps_it_gave_before():
+    from servebench.spec import load_reference
+    from servebench.weights import make_weights
+
+    spec = spec_from_dict(TINY)
+    out = load_reference(TINY).compare(
+        spec, make_weights(spec, 2**31 + 5), *_fixed_rows(), control=True)
+    np.testing.assert_allclose(out["gap"], PARENT_GAP, rtol=1e-6, atol=0)
+    np.testing.assert_allclose(out["control_gap"], PARENT_CONTROL_GAP,
+                               rtol=1e-6, atol=1e-7)
+    assert out["margin"] is None and out["route_shift"] is None
+
+
+def test_the_widest_gap_leaves_out_near_tied_tokens_only():
+    from servebench.runcell import gap_numbers
+
+    gaps = np.array([0.0, 0.5, 0.01, 0.0])
+    assert gap_numbers(gaps) == {"logit_gap": 0.5, "logit_gap_mean": 0.1275,
+                                 "argmax_miss_share": 0.5}
+    margin = np.array([0.2, 0.001, 0.05, 0.3])
+    tied = gap_numbers(gaps, margin, 0.004)
+    assert tied == {"logit_gap": 0.01, "logit_gap_mean": 0.1275,
+                    "argmax_miss_share": 0.5, "near_tie_share": 0.25}
+    assert gap_numbers(gaps, margin, 1.0)["logit_gap"] is None
+
+
+def test_every_configuration_names_its_reference(tmp_path):
+    from servebench.spec import load_reference, reference_path
+
+    bench = specmod.load_benchmark()
+    for c in bench["configs"]:
+        raw = json.loads((specmod.ROOT / c["file"]).read_text())
+        assert reference_path(raw).is_file()
+        assert callable(load_reference(raw).compare)
+    qwen = json.loads((specmod.HERE / "configs/qwen2-7b-14L.json")
+                      .read_text())
+    assert reference_path(qwen) == specmod.PACKAGE / "reference.py"
+    from servebench import reference
+    assert load_reference(qwen) is reference
+    with pytest.raises(KeyError, match="'reference'"):
+        load_reference({"name": "x"})
+    with pytest.raises(FileNotFoundError, match="'reference'.*nowhere.py"):
+        load_reference({"name": "x", "reference": str(tmp_path /
+                                                      "nowhere.py")})
+
+
+def test_a_configuration_runs_through_a_reference_of_its_own(tmp_path):
+    from servebench.spec import load_reference
+
+    config = dict(_moe(), reference=str(_reference_copy(tmp_path, "noted",
+                                                        NOTED)))
+    result, info = _run(config, seconds=2.0)
+    noted = load_reference(config).NOTED
+    assert len(noted) == 1 and noted[0] > 0
+    assert result["correct"] is True, result["checks"]
+    assert {"logit_gap", "near_tie_share"} <= set(result["checks"])
+
+
+def test_a_leaf_the_program_lacks_fails_before_serving(tmp_path):
+    config = dict(_moe(), reference=str(_reference_copy(tmp_path, "qk",
+                                                        EXTRA_LEAF)))
+    with pytest.raises(ValueError, match="q_norm"):
+        _run(config)
+
+
+def test_program_options_are_passed_through_by_name():
+    import dataclasses
+
+    from repro.configs.base import ArchConfig
+    from servebench.harness import arch_config
+
+    qwen = load_spec(specmod.HERE / "configs/qwen2-7b-14L.json")
+    assert arch_config(qwen) == ArchConfig(
+        name="qwen2-7b-14L", family="dense", n_layers=14, d_model=3584,
+        n_heads=28, n_kv_heads=4, d_ff=18944, vocab=152064, head_dim=128,
+        qkv_bias=True, rope_theta=1e6, tie_embeddings=False, norm_eps=1e-6,
+        n_experts=0, top_k=0, moe_d_ff=0, shared_expert=False)
+    moe = spec_from_dict(dict(_moe(), program={"capacity_factor": 2.0}))
+    assert arch_config(moe) == dataclasses.replace(
+        arch_config(spec_from_dict(_moe())), capacity_factor=2.0)
+    with pytest.raises(ValueError, match="qk_norm"):
+        arch_config(spec_from_dict(dict(_moe(), program={
+            "qk_norm": True, "capacity_factor": 2.0})))
 
 
 def _step_fault(kind):
