@@ -26,6 +26,9 @@ class ArchConfig:
     head_dim: Optional[int] = None   # default d_model // n_heads
     # attention flavour
     qkv_bias: bool = False
+    qk_proj_norm: bool = False       # olmoe: RMS norm over the whole q and
+                                     # k projections (not per head), before
+                                     # rotary
     rope_theta: float = 10_000.0
     sliding_window: int = 0          # >0: local-attention window size
     local_global_ratio: int = 0      # gemma3: N local layers per global layer
@@ -36,6 +39,7 @@ class ArchConfig:
     moe_d_ff: int = 0                # per-expert hidden dim (olmoe: 1024)
     shared_expert: bool = False      # llama4: always-on shared FFN
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalise the top-k gates (olmoe: no)
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
     ssm_head_dim: int = 64

@@ -57,7 +57,7 @@ def get_model(cfg: ArchConfig, moe_impl: str = "sorted") -> Model:
             prefill=lambda p, batch, max_len: moe.prefill(
                 cfg, p, batch["tokens"], max_len, impl=moe_impl),
             decode=partial(moe.decode_step, cfg, impl=moe_impl),
-            init_cache=partial(tfm.init_cache, cfg),
+            init_cache=partial(moe.init_cache, cfg),
         )
     if fam == "ssm":
         return Model(

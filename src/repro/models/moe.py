@@ -20,7 +20,7 @@ Two implementations:
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,11 +56,14 @@ def init_moe_params(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
 
 
 def _route(cfg: ArchConfig, router_w: Array, xt: Array):
-    """xt (T, D) -> (probs (T, K), idx (T, K), aux load-balance loss)."""
+    """xt (T, D) -> (probs (T, K), idx (T, K), aux load-balance loss).
+    The top-k probabilities are renormalised to sum to one only with
+    ``cfg.norm_topk_prob``; OLMoE gates by the raw softmax."""
     logits = xt.astype(jnp.float32) @ router_w  # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, cfg.top_k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
     # Switch-style load-balancing aux loss: E * sum_e f_e * p_e
     E = probs.shape[-1]
     me = probs.mean(0)
@@ -70,8 +73,12 @@ def _route(cfg: ArchConfig, router_w: Array, xt: Array):
     return top_p, top_i, aux
 
 
-def moe_ffn_dense(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
-    """Oracle path: run all experts on all tokens (small shapes only)."""
+def moe_ffn_dense(cfg: ArchConfig, lp, h: Array
+                  ) -> Tuple[Array, Array, Array]:
+    """Oracle path: run all experts on all tokens (small shapes only).
+    Like every dispatch of :data:`MOE_IMPLS` it returns the layer's
+    output, its aux loss and the experts each token was routed to
+    (T, K)."""
     B, S, D = h.shape
     xt = h.reshape(-1, D)
     top_p, top_i, aux = _route(cfg, lp["router"], xt)
@@ -81,7 +88,7 @@ def moe_ffn_dense(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
     u = jnp.einsum("td,edf->tef", xt, lp["we_up"])
     y = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u, lp["we_down"])
     out = jnp.einsum("ted,te->td", y.astype(jnp.float32), gates)
-    return out.reshape(B, S, D).astype(h.dtype), aux
+    return out.reshape(B, S, D).astype(h.dtype), aux, top_i
 
 
 def capacity(cfg: ArchConfig, T: int) -> int:
@@ -89,7 +96,8 @@ def capacity(cfg: ArchConfig, T: int) -> int:
     return max(8, -(-c // 8) * 8)
 
 
-def moe_ffn_sorted(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
+def moe_ffn_sorted(cfg: ArchConfig, lp, h: Array
+                   ) -> Tuple[Array, Array, Array]:
     """Production path: sort-based capacity dispatch (see module doc)."""
     B, S, D = h.shape
     T = B * S
@@ -121,10 +129,11 @@ def moe_ffn_sorted(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
     y_tok = jnp.where(keep[:, None], y_tok, 0)
     out = jnp.zeros((T, D), jnp.float32)
     out = out.at[tok].add(y_tok.astype(jnp.float32) * w[:, None])
-    return out.reshape(B, S, D).astype(h.dtype), aux
+    return out.reshape(B, S, D).astype(h.dtype), aux, top_i
 
 
-def moe_ffn_a2a(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
+def moe_ffn_a2a(cfg: ArchConfig, lp, h: Array
+                ) -> Tuple[Array, Array, Array]:
     """Expert-parallel dispatch via shard_map (Perf iteration, see
     EXPERIMENTS.md §Perf olmoe/llama4).
 
@@ -204,7 +213,7 @@ def moe_ffn_a2a(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
         out = jax.lax.psum_scatter(out, "model", scatter_dimension=1,
                                    tiled=True)
         aux = jax.lax.pmean(aux, "model")
-        return out.astype(h_loc.dtype), aux
+        return out.astype(h_loc.dtype), aux, top_i
 
     w_up_spec = P("model", None, "data" if fsdp_weights else None)
     w_dn_spec = P("model", "data" if fsdp_weights else None, None)
@@ -212,11 +221,10 @@ def moe_ffn_a2a(cfg: ArchConfig, lp, h: Array) -> Tuple[Array, Array]:
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   w_up_spec, w_up_spec, w_dn_spec),
-        out_specs=(P(dp, "model", None), P()),
+        out_specs=(P(dp, "model", None), P(), P(dp, None)),
         check_vma=False,
     )
-    out, aux = fn(h, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"])
-    return out, aux
+    return fn(h, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"])
 
 
 MOE_IMPLS = {"dense": moe_ffn_dense, "sorted": moe_ffn_sorted,
@@ -228,27 +236,33 @@ MOE_IMPLS = {"dense": moe_ffn_dense, "sorted": moe_ffn_sorted,
 # ---------------------------------------------------------------------------
 
 
+def moe_layer(cfg: ArchConfig, impl: str, lp, x, window, *, cache_kv=None,
+              pos=0, block_k=1024):
+    """One MoE transformer block: the dense attention half, then the
+    ``impl`` expert dispatch (plus the shared expert).  Returns the
+    residual stream, the new (k, v), the aux loss and the experts each
+    token was routed to (T, K)."""
+    x, h, new_kv = tfm.attention_block(cfg, lp, x, window, cache_kv=cache_kv,
+                                       pos=pos, block_k=block_k)
+    delta, aux, top_i = MOE_IMPLS[impl](cfg, lp, h)
+    if cfg.shared_expert:
+        delta = delta + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+    return x + delta, new_kv, aux, top_i
+
+
 def forward(cfg: ArchConfig, params: Params, tokens: Array,
             impl: str = "sorted", block_k: int = 1024
             ) -> Tuple[Array, Array]:
     x = params["embed"].astype(jnp.bfloat16)[tokens]
     windows = jnp.asarray(tfm.layer_windows(cfg))
     lp = params["layers"]
-    moe_fn = MOE_IMPLS[impl]
 
     def body(carry, xs):
         x, aux = carry
         lp_l, window = xs
-        h = hint(rms_norm(x, lp_l["ln1"], cfg.norm_eps), "block_in")
-        k, v = tfm._project_kv(cfg, lp_l, h, 0)
-        attn = tfm._attn(cfg, lp_l, h, k_full=k, v_full=v, window=window,
-                         q_offset=0, kv_len=None, block_k=block_k)
-        x = x + attn
-        h = hint(rms_norm(x, lp_l["ln2"], cfg.norm_eps), "block_in")
-        delta, aux_l = moe_fn(cfg, lp_l, h)
-        if cfg.shared_expert:
-            delta = delta + swiglu(h, lp_l["w_gate"], lp_l["w_up"], lp_l["w_down"])
-        return (hint(x + delta, "residual"), aux + aux_l), None
+        x, _, aux_l, _ = moe_layer(cfg, impl, lp_l, x, window,
+                                   block_k=block_k)
+        return (hint(x, "residual"), aux + aux_l), None
 
     body = jax.checkpoint(body, prevent_cse=False)
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
@@ -263,33 +277,63 @@ def lm_loss(cfg: ArchConfig, params: Params, batch: Dict[str, Array],
     return tfm.chunked_xent(cfg, params, h, batch["labels"]) + aux_weight * aux
 
 
-def decode_step(cfg: ArchConfig, params: Params, cache: tfm.KVCache,
+# the route counts of each layer, in the order ``route_counts`` gives them;
+# ``Server`` puts them on its spans and counters under these names
+ROUTE_COUNTS = ("moe_experts_routed", "moe_experts_read", "moe_dropped")
+
+
+class MoECache(NamedTuple):
+    """The dense KV cache of an MoE model, and the expert traffic of the
+    step that last wrote it."""
+    k: Array        # (L, B, Smax, Hkv, Dh)
+    v: Array
+    pos: Array      # scalar int32: number of valid tokens
+    routes: Array   # (L, 3) int32: route_counts of each layer
+
+
+def init_cache(cfg: ArchConfig, batch: int, max_len: int,
+               dtype=jnp.bfloat16) -> MoECache:
+    kv = tfm.init_cache(cfg, batch, max_len, dtype)
+    return MoECache(kv.k, kv.v, kv.pos,
+                    jnp.zeros((cfg.n_layers, len(ROUTE_COUNTS)), jnp.int32))
+
+
+def route_counts(cfg: ArchConfig, impl: str, top_i: Array) -> Array:
+    """One layer's expert traffic for a step whose T rows routed to
+    ``top_i`` (T, K), int32 in the order of :data:`ROUTE_COUNTS`: the
+    distinct experts routed to; the experts whose weights the ``impl``
+    dispatch multiplies (every one: the einsums of ``dense`` and
+    ``sorted``, and of ``a2a`` summed over its shards, cover all E); and
+    the token-expert pairs dropped past capacity (none for ``dense``;
+    ``a2a`` counts as its one-device fallback, ``sorted``)."""
+    E = cfg.n_experts
+    per_expert = jnp.zeros((E,), jnp.int32).at[top_i.reshape(-1)].add(1)
+    routed = jnp.sum(per_expert > 0)
+    dropped = (jnp.zeros((), jnp.int32) if impl == "dense" else jnp.sum(
+        jnp.maximum(per_expert - capacity(cfg, top_i.shape[0]), 0)))
+    return jnp.stack([routed, jnp.asarray(E), dropped]).astype(jnp.int32)
+
+
+def decode_step(cfg: ArchConfig, params: Params, cache: MoECache,
                 tokens: Array, impl: str = "sorted", block_k: int = 1024
-                ) -> Tuple[Array, tfm.KVCache]:
+                ) -> Tuple[Array, MoECache]:
     x = params["embed"].astype(jnp.bfloat16)[tokens]
     windows = jnp.asarray(tfm.layer_windows(cfg))
     lp = params["layers"]
     pos = cache.pos
-    moe_fn = MOE_IMPLS[impl]
 
     def body(x, xs):
         lp_l, window, kc, vc = xs
+        x, (kc, vc), _, top_i = moe_layer(cfg, impl, lp_l, x, window,
+                                          cache_kv=(kc, vc), pos=pos,
+                                          block_k=block_k)
+        return x, (kc, vc, route_counts(cfg, impl, top_i))
 
-        def ffn(lp_, hnorm):
-            delta, _ = moe_fn(cfg, lp_, hnorm)
-            if cfg.shared_expert:
-                delta = delta + swiglu(hnorm, lp_["w_gate"], lp_["w_up"],
-                                       lp_["w_down"])
-            return delta
-
-        x, (kc, vc) = tfm.dense_layer(cfg, lp_l, x, window, cache_kv=(kc, vc),
-                                      pos=pos, block_k=block_k, ffn=ffn)
-        return x, (kc, vc)
-
-    x, (k_new, v_new) = jax.lax.scan(body, x, (lp, windows, cache.k, cache.v))
+    x, (k_new, v_new, routes) = jax.lax.scan(
+        body, x, (lp, windows, cache.k, cache.v))
     h = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = tfm.logits_fn(cfg, params, h)[:, 0]
-    return logits, tfm.KVCache(k_new, v_new, pos + 1)
+    return logits, MoECache(k_new, v_new, pos + 1, routes)
 
 
 def prefill(cfg: ArchConfig, params: Params, tokens: Array, max_len: int,
@@ -298,27 +342,18 @@ def prefill(cfg: ArchConfig, params: Params, tokens: Array, max_len: int,
     x = params["embed"].astype(jnp.bfloat16)[tokens]
     windows = jnp.asarray(tfm.layer_windows(cfg))
     lp = params["layers"]
-    moe_fn = MOE_IMPLS[impl]
 
     def body(x, xs):
         lp_l, window = xs
-
-        def ffn(lp_, hnorm):
-            delta, _ = moe_fn(cfg, lp_, hnorm)
-            if cfg.shared_expert:
-                delta = delta + swiglu(hnorm, lp_["w_gate"], lp_["w_up"],
-                                       lp_["w_down"])
-            return delta
-
-        x, (k, v) = tfm.dense_layer(cfg, lp_l, x, window, block_k=block_k,
-                                    ffn=ffn)
+        x, (k, v), _, top_i = moe_layer(cfg, impl, lp_l, x, window,
+                                        block_k=block_k)
         pad = max_len - S
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return x, (k, v)
+        return x, (k, v, route_counts(cfg, impl, top_i))
 
     body = jax.checkpoint(body, prevent_cse=False)
-    x, (ks, vs) = jax.lax.scan(body, x, (lp, windows))
+    x, (ks, vs, routes) = jax.lax.scan(body, x, (lp, windows))
     h = rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = tfm.logits_fn(cfg, params, h[:, -1:])[:, 0]
-    return logits, tfm.KVCache(ks, vs, jnp.asarray(S, jnp.int32))
+    return logits, MoECache(ks, vs, jnp.asarray(S, jnp.int32), routes)

@@ -21,7 +21,8 @@ import numpy as np
 
 from ..configs.base import ArchConfig
 from ..parallel.hints import hint
-from .layers import apply_rope, chunked_attention, dense_init, rms_norm, split_keys
+from .layers import (apply_rope, chunked_attention, dense_init, rms_norm,
+                     split_keys, swiglu)
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -72,6 +73,9 @@ def init_dense_params(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
         p["layers"]["bq"] = jnp.zeros((L, H * Dh), dtype)
         p["layers"]["bk"] = jnp.zeros((L, Hkv * Dh), dtype)
         p["layers"]["bv"] = jnp.zeros((L, Hkv * Dh), dtype)
+    if cfg.qk_proj_norm:
+        p["layers"]["q_norm"] = jnp.zeros((L, H * Dh), dtype)
+        p["layers"]["k_norm"] = jnp.zeros((L, Hkv * Dh), dtype)
     if not cfg.tie_embeddings:
         p["lm_head"] = dense_init(ks[2], (V, D), scale=0.02, dtype=dtype)
     return p
@@ -82,39 +86,53 @@ def init_dense_params(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _attn(cfg: ArchConfig, lp, x, *, k_full, v_full, window, q_offset,
-          kv_len, block_k=1024):
-    B, S, D = x.shape
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+def _project_q(cfg: ArchConfig, lp, x, q_offset):
+    """Rotated queries (B, S, H, Dh).  With ``cfg.qk_proj_norm`` the whole
+    projection (all H * Dh of it, not each head) is RMS-normed first, as
+    OLMoE does."""
+    B, S, _ = x.shape
     q = jnp.einsum("bsd,dh->bsh", x, lp["wq"])
     if "bq" in lp:
         q = q + lp["bq"]
-    q = q.reshape(B, S, H, Dh)
-    q = apply_rope(q, jnp.arange(S) + q_offset, cfg.rope_theta)
+    if cfg.qk_proj_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+    q = q.reshape(B, S, cfg.n_heads, cfg.hd)
+    return apply_rope(q, jnp.arange(S) + q_offset, cfg.rope_theta)
+
+
+def _attn(cfg: ArchConfig, lp, x, *, k_full, v_full, window, q_offset,
+          kv_len, block_k=1024):
+    B, S = x.shape[:2]
+    q = _project_q(cfg, lp, x, q_offset)
     out = chunked_attention(
         q, k_full, v_full, causal=True, window=window,
         q_offset=q_offset, kv_len=kv_len, block_k=block_k)
-    return out.reshape(B, S, H * Dh) @ lp["wo"]
+    return out.reshape(B, S, cfg.n_heads * cfg.hd) @ lp["wo"]
 
 
 def _project_kv(cfg, lp, x, q_offset):
+    """Rotated keys and values (B, S, Hkv, Dh); keys are normed over the
+    whole projection width first with ``cfg.qk_proj_norm``."""
     B, S, _ = x.shape
     Hkv, Dh = cfg.n_kv_heads, cfg.hd
     k = jnp.einsum("bsd,dh->bsh", x, lp["wk"])
     v = jnp.einsum("bsd,dh->bsh", x, lp["wv"])
     if "bk" in lp:
         k, v = k + lp["bk"], v + lp["bv"]
+    if cfg.qk_proj_norm:
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     k = k.reshape(B, S, Hkv, Dh)
     v = v.reshape(B, S, Hkv, Dh)
     k = apply_rope(k, jnp.arange(S) + q_offset, cfg.rope_theta)
     return k, v
 
 
-def dense_layer(cfg: ArchConfig, lp, x, window, *, cache_kv=None, pos=0,
-                block_k=1024, ffn=None):
-    """One transformer block.  cache_kv=(k,v) full-length buffers for decode;
-    otherwise self-attention over the current sequence.  ``ffn(lp, h)``
-    overrides the feed-forward (used by the MoE model)."""
+def attention_block(cfg: ArchConfig, lp, x, window, *, cache_kv=None,
+                    pos=0, block_k=1024):
+    """The attention half of a transformer block: the residual stream after
+    attention, the normed input of the feed-forward, and the new (k, v).
+    cache_kv=(k,v) full-length buffers for decode; otherwise
+    self-attention over the current sequence."""
     h = hint(rms_norm(x, lp["ln1"], cfg.norm_eps), "block_in")
     if cache_kv is None:
         k, v = _project_kv(cfg, lp, h, pos)
@@ -132,14 +150,16 @@ def dense_layer(cfg: ArchConfig, lp, x, window, *, cache_kv=None, pos=0,
                      q_offset=pos, kv_len=pos + x.shape[1], block_k=block_k)
         new_kv = (k_buf, v_buf)
     x = x + attn
-    h = hint(rms_norm(x, lp["ln2"], cfg.norm_eps), "block_in")
-    if ffn is None:
-        from .layers import swiglu
-        delta = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-    else:
-        delta = ffn(lp, h)
-    x = x + delta
-    return x, new_kv
+    return x, hint(rms_norm(x, lp["ln2"], cfg.norm_eps), "block_in"), new_kv
+
+
+def dense_layer(cfg: ArchConfig, lp, x, window, *, cache_kv=None, pos=0,
+                block_k=1024):
+    """One transformer block: :func:`attention_block`, then the SwiGLU
+    feed-forward."""
+    x, h, new_kv = attention_block(cfg, lp, x, window, cache_kv=cache_kv,
+                                   pos=pos, block_k=block_k)
+    return x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), new_kv
 
 
 # ---------------------------------------------------------------------------
@@ -358,11 +378,7 @@ def grouped_decode_step(cfg: ArchConfig, params: Params,
         # ring holds the last W tokens; absolute position of ring row r is
         # recovered from the bank equation -- attention over W rows, masked
         # by true recency.  kv_len = min(pos+1, W): all rows valid once full.
-        q = jnp.einsum("bsd,dh->bsh", h, lp["wq"])
-        if "bq" in lp:
-            q = q + lp["bq"]
-        q = q.reshape(B, S, H, Dh)
-        q = apply_rope(q, jnp.arange(S) + pos, cfg.rope_theta)
+        q = _project_q(cfg, lp, h, pos)
         # positions of ring rows: row r came from pos' = r + W*floor(...) --
         # reconstruct: rows (slot-W, slot] hold positions (pos-W, pos]
         row = jnp.arange(W)
@@ -379,7 +395,6 @@ def grouped_decode_step(cfg: ArchConfig, params: Params,
         o = (o / jnp.maximum(p.sum(-1)[..., None], 1e-30)).reshape(B, S, H * Dh)
         x = x + o.astype(x.dtype) @ lp["wo"]
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        from .layers import swiglu
         return x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"]), kc, vc
 
     def group_body(x, xs):
@@ -465,7 +480,6 @@ def decode_step_quant(cfg: ArchConfig, params: Params, cache: QuantKVCache,
                      q_offset=pos, kv_len=pos + x.shape[1], block_k=block_k)
         x = x + attn
         h = hint(rms_norm(x, lp_l["ln2"], cfg.norm_eps), "block_in")
-        from .layers import swiglu
         x = x + swiglu(h, lp_l["w_gate"], lp_l["w_up"], lp_l["w_down"])
         return x, (kq, vq, ks, vs)
 

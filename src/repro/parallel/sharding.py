@@ -217,6 +217,9 @@ def cache_specs(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh) -> Any:
             kv = P(None, None, tuple(a for a in (*dp, "model")), None, None)
         else:
             kv = P(None, nb, s_ax, h_ax, None)
+        if fam == "moe":
+            from ..models.moe import MoECache
+            return MoECache(k=kv, v=kv, pos=P(), routes=P())
         from ..models.transformer import KVCache
         return KVCache(k=kv, v=kv, pos=P())
     if fam == "ssm":

@@ -42,6 +42,12 @@ land on the innermost span that ran them; ``serve.queue_wait`` records
 each request's time from ``submit`` to admission.  Once a tick the
 served artifact's kernel builds and calls are published as the gauges
 ``banked_kernel_builds`` and ``banked_kernel_calls`` (label ``op``).
+A mixture of experts' step also leaves its expert traffic in the cache
+it returns (``MoECache.routes``); the server reads it with the step's
+tokens, in the same blocking read, and puts it, summed over layers, on
+``serve.step`` (and on ``serve.admit``, summed over the prefill calls) as
+the attributes ``moe_experts_routed``, ``moe_experts_read`` and
+``moe_dropped``, and on counters of the same names.
 """
 
 from __future__ import annotations
@@ -64,6 +70,7 @@ from ..core.service import (JointTicket, PlanService, PlanTicket,
 from ..core.polytope import Affine, MemorySpec
 from ..core.tracing import new_trace_id, serve_tracer
 from ..models import Model
+from ..models.moe import ROUTE_COUNTS, MoECache
 from ..launch import steps as steps_mod
 
 
@@ -586,17 +593,23 @@ class Server:
                 tr.record(tid, "serve.queue_wait", req.queued_at,
                           time.perf_counter(), uid=req.uid)
             with tr.span(tid, "serve.admit", uid=req.uid, slot=slot,
-                         prompt_tokens=len(req.prompt)):
+                         prompt_tokens=len(req.prompt)) as admit_span:
                 self.positions[slot] = 0
                 # per-request prefill: run the prompt through decode one
                 # token at a time into this slot (batch=1 prefill folded
                 # into the shared cache; a production server runs a
                 # separate prefill graph)
+                routes = []
                 for t in req.prompt:
                     self.tokens = self.tokens.at[slot, 0].set(int(t))
                     nxt, _, self.cache = self._decode(
                         self._params, self.cache, self.tokens)
+                    if isinstance(self.cache, MoECache):
+                        routes.append(self.cache.routes)
                     self._record(slot, int(t))
+                if routes:
+                    nxt, routes = jax.device_get((nxt, routes))
+                    self._note_routes(tr, admit_span, np.sum(routes, axis=0))
                 nxt_tok = int(np.asarray(nxt)[slot, 0])
                 req._next = nxt_tok
                 self._record(slot, nxt_tok)   # the next tick's decode input
@@ -672,11 +685,18 @@ class Server:
         with tr.span(tid, "serve.inputs"):
             for slot in self.active:
                 self.tokens = self.tokens.at[slot, 0].set(nxt_in[slot])
-        with tr.span(tid, "serve.step"):
+        with tr.span(tid, "serve.step") as step_span:
             nxt, _, self.cache = self._decode(self._params, self.cache,
                                               self.tokens)
+            routes = (self.cache.routes if isinstance(self.cache, MoECache)
+                      else None)
             with tr.span(tid, "serve.step.wait"):
-                nxt = np.asarray(nxt)
+                if routes is None:
+                    nxt = np.asarray(nxt)
+                else:
+                    nxt, routes = jax.device_get((nxt, routes))
+            if routes is not None:
+                self._note_routes(tr, step_span, routes)
         slots = len(self.active)
         with tr.span(tid, "serve.emit"):
             finished = []
@@ -696,6 +716,16 @@ class Server:
             self._flush_records()   # this tick's records land this tick
         self.ticks += 1
         tick_span.attrs.update(slots=slots, tokens=slots)
+
+    @staticmethod
+    def _note_routes(tr, span, routes) -> None:
+        """A mixture of experts' step traffic, per layer (L, 3) in the
+        order of ``moe.ROUTE_COUNTS``: summed over layers onto ``span``
+        under those names, and added to the counters of those names."""
+        for name, n in zip(ROUTE_COUNTS, np.sum(routes, axis=0).tolist()):
+            span.attrs[name] = n
+            if tr.metrics is not None:
+                tr.metrics.inc(name, n)
 
     def run(self, max_ticks: int = 1000):
         while (self.queue or self.active) and self.ticks < max_ticks:

@@ -113,6 +113,29 @@ def test_mini_dryrun_multipod():
     assert "DRYRUN-OK" in out
 
 
+@pytest.mark.parametrize("variant", ["baseline", "moe_a2a"])
+def test_mini_dryrun_moe_decode(variant):
+    """An MoE decode cell lowers on a (2, 2) mesh: its cache specs follow
+    the MoE cache's fields (subprocess: 4 devices)."""
+    out = _run_subprocess(f"""
+        import dataclasses, jax
+        import repro.launch.dryrun as dr
+        import repro.configs as C
+
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        cfg = C.get_arch("olmoe_1b_7b").reduced()
+        dr.get_arch = lambda n: cfg
+        dr.SHAPES = dict(dr.SHAPES, decode_32k=dataclasses.replace(
+            dr.SHAPES["decode_32k"], seq_len=64, global_batch=4))
+        r = dr.lower_cell("olmoe_1b_7b", "decode_32k", mesh,
+                          variant="{variant}")
+        assert r["flops"] > 0
+        print("DRYRUN-OK")
+    """)
+    assert "DRYRUN-OK" in out
+
+
 def test_logical_axis_cache_specs():
     from repro.configs import get_arch
     from repro.configs.base import SHAPES

@@ -137,7 +137,7 @@ def test_moe_sorted_vs_dense_oracle():
     params = moe_mod.init_moe_params(cfg, key)
     lp = jax.tree.map(lambda x: x[0], params["layers"])
     h = _rand((2, 16, cfg.d_model), jnp.float32)
-    yd, _ = moe_mod.moe_ffn_dense(cfg, lp, h)
-    ys, _ = moe_mod.moe_ffn_sorted(cfg, lp, h)
+    yd = moe_mod.moe_ffn_dense(cfg, lp, h)[0]
+    ys = moe_mod.moe_ffn_sorted(cfg, lp, h)[0]
     np.testing.assert_allclose(np.asarray(yd, np.float32),
                                np.asarray(ys, np.float32), atol=3e-2)

@@ -59,9 +59,10 @@ def test_moe_a2a_fallback_without_mesh():
     lp = jax.tree.map(lambda x: x[0], params["layers"])
     h = jnp.asarray(np.random.default_rng(0).normal(size=(2, 16, cfg.d_model)),
                     jnp.float32)
-    ys, _ = moe_mod.moe_ffn_sorted(cfg, lp, h)
-    ya, _ = moe_mod.moe_ffn_a2a(cfg, lp, h)
+    ys, _, routed_s = moe_mod.moe_ffn_sorted(cfg, lp, h)
+    ya, _, routed_a = moe_mod.moe_ffn_a2a(cfg, lp, h)
     np.testing.assert_allclose(np.asarray(ys), np.asarray(ya), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(routed_s), np.asarray(routed_a))
 
 
 @pytest.mark.slow
@@ -85,11 +86,15 @@ def test_moe_a2a_matches_oracle_on_mesh():
         lp = jax.tree.map(lambda x: x[0], params["layers"])
         h = jnp.asarray(np.random.default_rng(0).normal(
             size=(2, 16, cfg.d_model)), jnp.float32)
-        yd, _ = moe_mod.moe_ffn_dense(cfg, lp, h)
+        yd, _, routed_d = moe_mod.moe_ffn_dense(cfg, lp, h)
         with mesh, sharding_policy({"__mesh__": mesh}):
-            ya, _ = jax.jit(lambda l, x: moe_mod.moe_ffn_a2a(cfg, l, x))(lp, h)
+            ya, _, routed_a = jax.jit(
+                lambda l, x: moe_mod.moe_ffn_a2a(cfg, l, x))(lp, h)
         np.testing.assert_allclose(np.asarray(yd, np.float32),
                                    np.asarray(ya, np.float32), atol=3e-2)
+        # each token's experts, gathered from the data shards in order
+        np.testing.assert_array_equal(np.asarray(routed_d),
+                                      np.asarray(routed_a))
         print("A2A-OK")
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,

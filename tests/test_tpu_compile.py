@@ -4,7 +4,7 @@ The TPU compiler is installed even where no chip is attached: it compiles
 for a topology that is only described, and refuses what Mosaic or the
 device's memory would refuse (misaligned tiles, programs that do not fit).
 These tests keep the banked kernels and the model step that ``Server``
-runs compilable for v5e at the shapes it serves qwen2-7b with.
+runs compilable for v5e at the shapes it serves qwen2-7b and OLMoE with.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import qwen2_7b
+from repro.configs import olmoe_1b_7b, qwen2_7b
 from repro.core import compile_trivial
 from repro.core.planner import BankingPlanner
 from repro.runtime.server import _page_program
@@ -121,14 +121,14 @@ def test_banked_gather_compiles_for_wide_rows(one_chip, kv_artifacts,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_qwen2_serve_step_fits_one_v5e(one_chip):
-    """The 14-layer qwen2-7b decode step at published widths, with the
-    8 x 4096-token bf16 cache, compiles for v5e and fits its HBM."""
+def _assert_serve_step_fits(one_chip, arch):
+    """``arch``'s one-chip stage decode step, as ``Server`` jits it, at
+    its served batch and cache, compiles for v5e and fits its HBM."""
     from repro.launch.steps import make_serve_step
     from repro.models import get_model
 
-    model = get_model(qwen2_7b.one_chip_stage())
-    slots = qwen2_7b.STAGE_SLOTS
+    model = get_model(arch.one_chip_stage())
+    slots = arch.STAGE_SLOTS
 
     def placed(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
@@ -136,7 +136,7 @@ def test_qwen2_serve_step_fits_one_v5e(one_chip):
 
     params = placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     cache = placed(jax.eval_shape(
-        lambda: model.init_cache(slots, qwen2_7b.STAGE_MAX_LEN)))
+        lambda: model.init_cache(slots, arch.STAGE_MAX_LEN)))
     tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     compiled = jax.jit(make_serve_step(model)).lower(
         params, cache, tokens).compile()
@@ -147,3 +147,16 @@ def test_qwen2_serve_step_fits_one_v5e(one_chip):
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert peak < HBM_BYTES, peak
+
+
+def test_qwen2_serve_step_fits_one_v5e(one_chip):
+    """The 14-layer qwen2-7b decode step at published widths, with the
+    8 x 4096-token bf16 cache, compiles for v5e and fits its HBM."""
+    _assert_serve_step_fits(one_chip, qwen2_7b)
+
+
+def test_olmoe_serve_step_fits_one_v5e(one_chip):
+    """The 8-layer OLMoE-1B-7B decode step at published widths (64
+    experts, top-8, the q/k norm), with the 8 x 4096-token bf16 cache,
+    compiles for v5e and fits its HBM."""
+    _assert_serve_step_fits(one_chip, olmoe_1b_7b)
